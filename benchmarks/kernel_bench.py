@@ -228,13 +228,13 @@ def build_bench(n_docs: int, dim: int) -> List[Dict]:
     the staged BuildPipeline (docs/DESIGN.md §8).  The sharded build runs
     the SAME stages row-parallel under ``shard_map`` over every available
     device (1 device still exercises the psum path)."""
-    from repro.core import builder
+    from repro.core import builder, distributed
 
     rng = np.random.default_rng(0)
     n_dev = len(jax.devices())
     n_docs -= n_docs % n_dev  # divisibility for the doc shards
     vecs = jnp.asarray(rng.normal(size=(n_docs, dim)).astype(np.float32))
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = distributed.make_mesh((n_dev,), ("data",))
     rows: List[Dict] = []
     for cfg in (
         FakeWordsConfig(quantization=50),

@@ -476,7 +476,6 @@ class BuildPipeline:
         """The ``shard_map``-wrapped per-shard build: ``fn(vectors) ->
         index`` with doc-sharded leaves.  Reusable across calls (jit caches
         one compilation) — ``build_sharded`` is the one-shot convenience."""
-        from repro import compat
         from repro.core import distributed
 
         axes = tuple(axes)
@@ -501,7 +500,7 @@ class BuildPipeline:
         # Replicated leaves (idf/df, reduction model) come out of psums the
         # static replication checker cannot always prove; disable it — the
         # sharded==local parity tests are the real guarantee.
-        return compat.shard_map(
+        return jax.shard_map(
             local_build, mesh=mesh, in_specs=jax.sharding.PartitionSpec(axes, None),
             out_specs=out_specs, check_vma=False,
         )

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import pca
 from repro.core import pipeline as pl
 from repro.core.blockmax import BlockMaxIndex
@@ -55,11 +54,22 @@ from repro.core.types import (
 )
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """A device mesh with Auto axis types.  ``jax.make_mesh`` defaults to
+    Explicit axes, under which the doc-sharded build and search (eager
+    ``shard_map`` calls, gathers on sharded leaves) would have to run
+    inside ``jax.set_mesh``; Auto axes let them run as plain calls."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
+
+
 def flat_axis_index(axes: Sequence[str]) -> jax.Array:
     """Row-major linear index of this shard over multiple mesh axes."""
     idx = jnp.int32(0)
     for name in axes:
-        idx = idx * compat.axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 
@@ -429,7 +439,7 @@ def make_sharded_search(
         in_specs = in_specs + (P(axes),)
     # After the full all-gather + top_k the outputs are bitwise-replicated,
     # but the static VMA checker cannot prove it; disable the check.
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -464,13 +474,16 @@ def build_blockmax_sharded(
             idx, block_size, mode=mode, signed_store=signed_store
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local_build,
         mesh=mesh,
         in_specs=(index_pspec(index, axes),),
         out_specs=P(axes, None),  # prefix: the one array leaf (ub)
     )
-    return fn(index)
+    # Under the mesh context so a mesh with Explicit axes (jax.make_mesh's
+    # default) can run the local gathers as well as an Auto one.
+    with jax.set_mesh(mesh):
+        return fn(index)
 
 
 def shard_blockmax(

@@ -299,20 +299,20 @@ class ExecutableCache:
             self._entries.move_to_end(full_key)
             self.hits += 1
             return hit
-        jitted = jax.jit(build_fn(), donate_argnums=donate_argnums)
-        try:
-            exe = jitted.lower(*args).compile()
-        except Exception:
-            # AOT lowering is an optimization (pins avals, honest compile
-            # accounting); a backend that rejects it still serves via the
-            # plain jit path.
-            exe = jitted
+        exe = jax.jit(build_fn(), donate_argnums=donate_argnums).lower(
+            *args
+        ).compile()
         self.compiles += 1
         self._entries[full_key] = exe
         while len(self._entries) > self.capacity:
             self._entries.popitem(last=False)
             self.evictions += 1
         return exe
+
+    def executables(self, kind: str) -> List[Any]:
+        """The cached executables of one kind ("search" or "append")."""
+        return [exe for (key, _, _), exe in self._entries.items()
+                if key[0] == kind]
 
     def clear(self) -> None:
         self._entries.clear()
@@ -366,7 +366,7 @@ class PackedSegments:
         return (not self.any_deleted) and self.n_rows == self.bucket
 
 
-def _stats_static(config) -> bool:
+def stats_static(config) -> bool:
     """Encodings whose stat views keep per-doc leaves untouched across
     refreshes (only GLOBAL leaves move), making append-only incremental
     repack sound.  Classic fake words rebuild ``scored``/``pq`` per row
@@ -403,7 +403,7 @@ def _try_append(
     a donated dynamic_update_slice; None when ineligible (full repack)."""
     k = len(prior.seg_names)
     if not (
-        _stats_static(config)
+        stats_static(config)
         and bucket == prior.bucket
         and len(names) > k
         and names[:k] == prior.seg_names

@@ -1112,11 +1112,13 @@ class IndexWriter:
                 use_kernel=self.use_kernel,
                 global_stats=self.global_stats,
             )
-            if old is not None:
+            if old is not None and packed_mod.stats_static(self.config):
                 # Hand the old snapshot's packed buffers to the new reader:
                 # an append-only refresh absorbs them via a donated
                 # incremental repack (core/packed.py).  The old reader
-                # lazily repacks if searched again after donation.
+                # lazily repacks if searched again after donation.  Other
+                # encodings repack fully, so the old buffers stay with the
+                # old reader and are freed with it, before the new pack.
                 self._reader._packed_prior = old._packed
                 old._packed = None
             self._changed = False

@@ -10,21 +10,10 @@ import os
 from typing import Any
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
-# CPU containers run every kernel in interpret mode; on a real TPU leave unset.
-INTERPRET = jax.default_backend() != "tpu" or bool(
-    int(os.environ.get("REPRO_PALLAS_INTERPRET", "0"))
-)
-
-# --------------------------------------------------------------------------
-# Pallas TPU API version shim.  JAX renamed ``pltpu.TPUMemorySpace`` /
-# ``pltpu.TPUCompilerParams`` to ``MemorySpace`` / ``CompilerParams``; kernels
-# import the names from here so both JAX generations work (0.4.x pins the old
-# spelling).
-# --------------------------------------------------------------------------
-MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# Pallas kernels compile through Mosaic on a TPU and run in interpret mode
+# everywhere else (the kernel body executes on the host, for tests on CPU).
+INTERPRET = jax.default_backend() != "tpu"
 
 # Default for the ``use_kernel`` routing flags on the search hot paths: the
 # fused Pallas path on real TPUs, the XLA reference path elsewhere (tests
@@ -54,9 +43,11 @@ def next_pow2(x: int) -> int:
 # --------------------------------------------------------------------------
 # Canonical int4 nibble unpack / grouped-scale dequantization
 # (docs/DESIGN.md §12).  Lives here — the dependency-free kernel utility
-# module — so the Pallas kernel tiles, the XLA reference scoring paths and
-# the build-time quantizer (core/builder.py) all run the EXACT same
-# operation sequence: bit-for-bit identical dequantized operands.
+# module — so the XLA reference scoring paths and the build-time quantizer
+# (core/builder.py) run the EXACT same operation sequence: bit-for-bit
+# identical dequantized operands.  The Pallas kernel tiles repeat the same
+# arithmetic per value in a [low | high] nibble layout
+# (``fused_topk.kernel._dequant_tile``).
 # --------------------------------------------------------------------------
 
 
@@ -64,8 +55,9 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     """uint8 nibble pairs -> interleaved nibble columns (..., 2C) uint8.
 
     Low nibble = even column, high nibble = odd column; interleaving is a
-    stack + reshape (pairwise, gather-free — the same trick as the bitonic
-    network's compare-exchange pairing)."""
+    stack + reshape (gather-free).  XLA paths only: Mosaic has no lane
+    interleave, so the kernels dequantize low and high nibbles as separate
+    halves (``fused_topk.kernel._dequant_tile``)."""
     import jax.numpy as jnp
 
     lo = packed & jnp.uint8(0xF)

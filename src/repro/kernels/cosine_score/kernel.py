@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
@@ -64,8 +65,8 @@ def cosine_scores(
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], dp.shape[0]), jnp.float32),
-        scratch_shapes=[common.MemorySpace.VMEM((bq, bn), jnp.float32)],
-        compiler_params=common.CompilerParams(
+        scratch_shapes=[pltpu.MemorySpace.VMEM((bq, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

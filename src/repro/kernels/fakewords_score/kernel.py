@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
@@ -76,8 +77,8 @@ def score_matmul(
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], dp.shape[0]), out_dtype),
-        scratch_shapes=[common.MemorySpace.VMEM((bq, bn), acc_dtype)],
-        compiler_params=common.CompilerParams(
+        scratch_shapes=[pltpu.MemorySpace.VMEM((bq, bn), acc_dtype)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -17,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
@@ -118,11 +119,11 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[
-            common.MemorySpace.VMEM((bq, d), jnp.float32),
-            common.MemorySpace.VMEM((bq, 1), jnp.float32),
-            common.MemorySpace.VMEM((bq, 1), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, d), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, 1), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -27,11 +27,12 @@ its best score cannot beat any query's current depth-th best (the dense-GEMM
 analogue of WAND block skipping).  Two merge strategies (``merge``):
 
   * "bitonic" (default) — bitonic per-tile pre-reduction: a vectorized
-    bitonic sort network (reshape-paired compare-exchanges, no gathers)
-    sorts the tile by (score desc, id asc), the top ``depth`` columns are
-    kept, and one bitonic merge stage folds them into the (sorted) running
-    best.  O(log^2 bn + log depth) vectorized steps per tile instead of
-    ``depth`` sequential max-extractions.
+    bitonic sort network (compare-exchange partners from lane rotations,
+    no gathers or reshapes) sorts the tile under (score desc, id asc), its
+    best ``dpad`` columns are kept, and one half-cleaner plus a bitonic
+    merge fold them into the (sorted) running best.  O(log^2 bn + log
+    depth) vectorized steps per tile instead of ``depth`` sequential
+    max-extractions.
   * "extract" — the original exact iterative max-extraction (kept for A/B
     profiling; identical results).
 
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
@@ -64,55 +66,52 @@ _INT_DTYPES = (jnp.int8, jnp.int32, jnp.uint32)
 # --------------------------------------------------------------------------
 
 
-def _cmp_exchange(s, i, j: int, k: int):
+def _better(s, i, ps, pi):
+    """(s, i) precedes (ps, pi) in the total order (score desc, id asc)."""
+    return (s > ps) | ((s == ps) & (i < pi))
+
+
+def _cmp_exchange(s, i, j: int, want_better):
     """One compare-exchange stage at stride ``j`` over lane axis 1.
 
-    Partner pairing is done by reshape (elements ``x`` and ``x + j`` pair up),
-    never by gather — TPU-friendly.  Direction follows the standard bitonic
-    network: descending where ``(index & k) == 0`` (``k == 0`` means a merge
-    stage: descending everywhere).  The comparator is the total order
-    (score desc, id asc), so equal scores order by minimum id.
+    Lane ``x`` pairs with lane ``x ^ j``.  The partner's value comes from two
+    lane rotations selected by an iota mask, never from a gather or a
+    reshape.  ``want_better`` (bool, same shape) marks the lanes that must
+    end up holding the better element of their pair; a lane takes its
+    partner exactly when its own rank disagrees with that (an XOR, so no
+    select between boolean vectors is needed).
     """
-    bq, n = s.shape
-    s4 = s.reshape(bq, n // (2 * j), 2, j)
-    i4 = i.reshape(bq, n // (2 * j), 2, j)
-    sa, sb = s4[:, :, 0], s4[:, :, 1]
-    ia, ib = i4[:, :, 0], i4[:, :, 1]
-    a_first = (sa > sb) | ((sa == sb) & (ia < ib))  # a precedes b in DESC
-    if k:
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-        desc = ((idx & k) == 0).reshape(1, n // (2 * j), 2, j)[:, :, 0]
-        keep = jnp.where(desc, a_first, ~a_first)
-    else:
-        keep = a_first
-    new_sa = jnp.where(keep, sa, sb)
-    new_sb = jnp.where(keep, sb, sa)
-    new_ia = jnp.where(keep, ia, ib)
-    new_ib = jnp.where(keep, ib, ia)
-    s = jnp.stack([new_sa, new_sb], axis=2).reshape(bq, n)
-    i = jnp.stack([new_ia, new_ib], axis=2).reshape(bq, n)
-    return s, i
-
-
-def _bitonic_sort_desc(s, i):
-    """Full bitonic sort of (bq, L) pairs by (score desc, id asc); L pow2."""
     n = s.shape[1]
+    lower = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) & j) == 0
+    ps = jnp.where(lower, pltpu.roll(s, n - j, 1), pltpu.roll(s, j, 1))
+    pi = jnp.where(lower, pltpu.roll(i, n - j, 1), pltpu.roll(i, j, 1))
+    take = _better(s, i, ps, pi) ^ want_better
+    return jnp.where(take, ps, s), jnp.where(take, pi, i)
+
+
+def _bitonic_sort_asc(s, i):
+    """Full bitonic sort of (bq, L) pairs, worst first and best last under
+    (score desc, id asc); L pow2."""
+    n = s.shape[1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     k = 2
     while k <= n:
+        asc = (idx & k) == 0
         j = k // 2
         while j >= 1:
-            s, i = _cmp_exchange(s, i, j, k if k < n else 0)
+            # Ascending block: the lower lane of a pair keeps the worse one.
+            s, i = _cmp_exchange(s, i, j, ((idx & j) == 0) ^ asc)
             j //= 2
         k *= 2
     return s, i
 
 
 def _bitonic_merge_desc(s, i):
-    """Merge a (bq, L) bitonic sequence (desc run ++ asc tail) to sorted
-    descending; L pow2."""
+    """Merge a (bq, L) bitonic sequence to sorted descending; L pow2."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     j = s.shape[1] // 2
     while j >= 1:
-        s, i = _cmp_exchange(s, i, j, 0)
+        s, i = _cmp_exchange(s, i, j, (idx & j) == 0)
         j //= 2
     return s, i
 
@@ -120,10 +119,12 @@ def _bitonic_merge_desc(s, i):
 def _merge_topk_bitonic(rs_ref, ri_ref, tile_s, tile_i) -> None:
     """Bitonic per-tile pre-reduction merge.
 
-    Sort the candidate tile, keep its top ``dpad`` columns, then bitonic-merge
-    against the running best (kept sorted descending as an invariant — both
-    the init fill and this merge preserve it).  ``dpad`` (the running width)
-    is a power of two on this path.
+    Sort the candidate tile ascending, so its best ``dpad`` columns are the
+    static tail slice.  The running best is kept sorted descending (the
+    init fill and this merge preserve it), so running ++ tail is bitonic:
+    one half-cleaner (an elementwise better-of) keeps the best ``dpad`` of
+    the union, and a bitonic merge sorts them descending again.  ``dpad``
+    (the running width) is a power of two on this path.
     """
     bq, dpad = rs_ref.shape
     pad_to = max(common.next_pow2(tile_s.shape[1]), dpad)
@@ -135,12 +136,15 @@ def _merge_topk_bitonic(rs_ref, ri_ref, tile_s, tile_i) -> None:
         tile_i = jnp.concatenate(
             [tile_i, jnp.full((bq, pad), BIG_ID, tile_i.dtype)], axis=1
         )
-    tile_s, tile_i = _bitonic_sort_desc(tile_s, tile_i)
-    comb_s = jnp.concatenate([rs_ref[...], tile_s[:, dpad - 1 :: -1]], axis=1)
-    comb_i = jnp.concatenate([ri_ref[...], tile_i[:, dpad - 1 :: -1]], axis=1)
-    comb_s, comb_i = _bitonic_merge_desc(comb_s, comb_i)
-    rs_ref[...] = comb_s[:, :dpad]
-    ri_ref[...] = comb_i[:, :dpad]
+    tile_s, tile_i = _bitonic_sort_asc(tile_s, tile_i)
+    top_s, top_i = tile_s[:, pad_to - dpad :], tile_i[:, pad_to - dpad :]
+    run_s, run_i = rs_ref[...], ri_ref[...]
+    take = _better(top_s, top_i, run_s, run_i)
+    s, i = _bitonic_merge_desc(
+        jnp.where(take, top_s, run_s), jnp.where(take, top_i, run_i)
+    )
+    rs_ref[...] = s
+    ri_ref[...] = i
 
 
 # --------------------------------------------------------------------------
@@ -374,11 +378,11 @@ def fused_topk(
             jax.ShapeDtypeStruct((qp.shape[0], dpad), jnp.int32),
         ],
         scratch_shapes=[
-            common.MemorySpace.VMEM((bq, bn), acc_dtype),
-            common.MemorySpace.VMEM((bq, dpad), jnp.float32),
-            common.MemorySpace.VMEM((bq, dpad), jnp.int32),
+            pltpu.MemorySpace.VMEM((bq, bn), acc_dtype),
+            pltpu.MemorySpace.VMEM((bq, dpad), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, dpad), jnp.int32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -405,7 +409,7 @@ def _fused_gathered_kernel(
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _score_tile(q_ref[...], d_ref[0], mode, acc_dtype)
+    acc_ref[...] += _score_tile(q_ref[0], d_ref[0], mode, acc_dtype)
 
     @pl.when(k == n_k - 1)
     def _merge():
@@ -413,7 +417,7 @@ def _fused_gathered_kernel(
         # Merge key = GLOBAL doc id: ties then resolve exactly like the dense
         # reference paths (lowest doc id), independent of the block-gather
         # order blockmax stage 1 produced.
-        ids = rid_ref[...]
+        ids = rid_ref[0]
         valid = ids < n_docs  # folds the blockmax padding mask
         tile_s = jnp.where(valid, tile_s, -jnp.inf)
         ids = jnp.where(valid, ids, BIG_ID)
@@ -422,8 +426,25 @@ def _fused_gathered_kernel(
 
     @pl.when(jnp.logical_and(j == n_j - 1, k == n_k - 1))
     def _flush():
-        s_ref[...] = rs_ref[...]
-        i_ref[...] = ri_ref[...]
+        s_ref[0] = rs_ref[...]
+        i_ref[0] = ri_ref[...]
+
+
+def _gathered_specs(b: int, bn: int, bk: int, dpad: int):
+    """Per-query operands carry a unit axis — query (B, 1, T), row ids
+    (B, 1, R), results (B, 1, dpad) — so every block's last two dims are
+    either (8, 128)-aligned or span the whole dimension, as Mosaic needs."""
+    q_spec = pl.BlockSpec((1, 1, bk), lambda i, j, k: (i, 0, k))
+    rid_spec = pl.BlockSpec((1, 1, bn), lambda i, j, k: (i, 0, j))
+    out_specs = [
+        pl.BlockSpec((1, 1, dpad), lambda i, j, k: (i, 0, 0)),
+        pl.BlockSpec((1, 1, dpad), lambda i, j, k: (i, 0, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((b, 1, dpad), jnp.float32),
+        jax.ShapeDtypeStruct((b, 1, dpad), jnp.int32),
+    ]
+    return q_spec, rid_spec, out_specs, out_shape
 
 
 @functools.partial(
@@ -481,6 +502,7 @@ def fused_topk_gathered(
     rp = common.pad_dim(row_ids.astype(jnp.int32), 1, bn, value=BIG_ID)
     dpad = _depth_pad(depth, merge)
     grid = (b, dp.shape[1] // bn, qp.shape[1] // bk)
+    q_spec, rid_spec, out_specs, out_shape = _gathered_specs(b, bn, bk, dpad)
 
     scores, ids = pl.pallas_call(
         functools.partial(
@@ -490,30 +512,24 @@ def fused_topk_gathered(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k: (i, k)),
+            q_spec,
             pl.BlockSpec((1, bn, bk), lambda i, j, k: (i, j, k)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (i, j)),
+            rid_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, dpad), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, dpad), lambda i, j, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, dpad), jnp.float32),
-            jax.ShapeDtypeStruct((b, dpad), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            common.MemorySpace.VMEM((1, bn), acc_dtype),
-            common.MemorySpace.VMEM((1, dpad), jnp.float32),
-            common.MemorySpace.VMEM((1, dpad), jnp.int32),
+            pltpu.MemorySpace.VMEM((1, bn), acc_dtype),
+            pltpu.MemorySpace.VMEM((1, dpad), jnp.float32),
+            pltpu.MemorySpace.VMEM((1, dpad), jnp.int32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(qp, dp, rp)
-    scores = scores[:, :depth]
-    ids = ids[:, :depth]
+    )(qp[:, None, :], dp, rp[:, None, :])
+    scores = scores[:, 0, :depth]
+    ids = ids[:, 0, :depth]
     return scores, jnp.where(scores == -jnp.inf, -1, ids)
 
 
@@ -527,11 +543,15 @@ def fused_topk_gathered(
 #     factorizes out of the dot: the int8 tile is cast to the query dtype
 #     (exact: |q| <= 127 is representable in bf16), f32-accumulated across
 #     K tiles, and the scale is applied ONCE per (query, doc) at merge time.
-#   * bits=4 — nibbles are unpacked (``common.unpack_int4``) and rescaled
-#     per group (``common.dequant_int4``) IN REGISTERS before the tile dot;
-#     the canonical ordering (f32 (nibble-8) * group_scale, one cast to the
-#     query dtype) is shared bit-for-bit with the XLA references and the
-#     build-time ``dequantize_postings``.
+#   * bits=4 — nibbles are unpacked and rescaled per group IN REGISTERS
+#     before the tile dot, in the canonical ordering of
+#     ``common.dequant_int4`` (f32 (nibble-8) * group_scale, one cast to the
+#     query dtype), so each dequantized value is bit-identical to the XLA
+#     references and the build-time ``dequantize_postings``.  Mosaic has no
+#     lane interleave, so the tile is laid out [low nibbles | high nibbles]
+#     and the query's columns are permuted to match outside the kernel
+#     (:func:`_int4_query`); the scales stream transposed, (groups, docs),
+#     so their blocks stay (8, 128)-aligned.
 #
 # Padding invariants: packed pad byte 0x88 decodes to nibble 8 on both
 # halves -> dequantized 0; scale pads are 0 (so any stray nibble still
@@ -546,11 +566,28 @@ def _dequant_tile(d, s, bits: int, group: int, q_dtype):
     """Unpack + rescale one packed doc tile in registers.
 
     bits=8: (bn, bk) int8 -> q_dtype (scale applied later, post-reduction).
-    bits=4: (bn, bk//2) packed + (bn, bk//group) scales -> (bn, bk) q_dtype.
+    bits=4: (bn, bk//2) packed + (bk//group, bn) transposed scales ->
+    (bn, bk) q_dtype laid out [low nibbles | high nibbles].
     """
     if bits == 8:
         return d.astype(q_dtype)
-    return common.dequant_int4(d, s, group, q_dtype)
+    d = d.astype(jnp.int32)
+    n_groups, bn = s.shape
+    per_group = group // 2  # packed bytes per scale group
+    s_cols = jnp.broadcast_to(
+        s[:, None, :], (n_groups, per_group, bn)
+    ).reshape(n_groups * per_group, bn).T  # (bn, bk//2): one scale per byte
+    lo = ((d & 0xF).astype(jnp.float32) - 8.0) * s_cols
+    hi = ((d >> 4).astype(jnp.float32) - 8.0) * s_cols
+    return jnp.concatenate([lo.astype(q_dtype), hi.astype(q_dtype)], axis=1)
+
+
+def _int4_query(qp, bk: int):
+    """Permute a padded query's columns so each ``bk`` block reads [even
+    columns | odd columns] — the [low | high] nibble layout of the dequant
+    tile (a packed byte holds column 2c low and 2c + 1 high)."""
+    b, t = qp.shape
+    return qp.reshape(b, t // bk, bk // 2, 2).swapaxes(2, 3).reshape(b, t)
 
 
 def _fused_topk_quantized_kernel(
@@ -619,7 +656,7 @@ def _quantized_operands(q, docs, scale, bits, group, bq, bn, bk):
     sp = common.pad_dim(common.pad_dim(scale, 0, bn), 1, bk // group)
     assert 2 * dp.shape[1] == qp.shape[1], (dp.shape, qp.shape)
     assert sp.shape[1] * group == qp.shape[1], (sp.shape, qp.shape)
-    return qp, dp, sp
+    return _int4_query(qp, bk), dp, jnp.swapaxes(sp, 0, 1)
 
 
 @functools.partial(
@@ -674,7 +711,7 @@ def fused_topk_quantized(
         s_spec = pl.BlockSpec((bn, 1), lambda i, j, k: (j, 0))
     else:
         d_spec = pl.BlockSpec((bn, bk // 2), lambda i, j, k: (j, k))
-        s_spec = pl.BlockSpec((bn, bk // group), lambda i, j, k: (j, k))
+        s_spec = pl.BlockSpec((bk // group, bn), lambda i, j, k: (k, j))
     operands = [qp, dp, sp]
     in_specs = [
         pl.BlockSpec((bq, bk), lambda i, j, k: (i, k)),
@@ -703,11 +740,11 @@ def fused_topk_quantized(
             jax.ShapeDtypeStruct((qp.shape[0], dpad), jnp.int32),
         ],
         scratch_shapes=[
-            common.MemorySpace.VMEM((bq, bn), jnp.float32),
-            common.MemorySpace.VMEM((bq, dpad), jnp.float32),
-            common.MemorySpace.VMEM((bq, dpad), jnp.int32),
+            pltpu.MemorySpace.VMEM((bq, bn), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, dpad), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, dpad), jnp.int32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -734,7 +771,7 @@ def _fused_gathered_quantized_kernel(
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...]
+    q = q_ref[0]
     d = _dequant_tile(d_ref[0], s_ref[0], bits, group, q.dtype)
     acc_ref[...] += jnp.dot(q, d.T, preferred_element_type=jnp.float32)
 
@@ -743,7 +780,7 @@ def _fused_gathered_quantized_kernel(
         tile_s = acc_ref[...]  # (1, bn)
         if bits == 8:
             tile_s = tile_s * s_ref[0][:, 0][None, :]
-        ids = rid_ref[...]
+        ids = rid_ref[0]
         valid = ids < n_docs
         tile_s = jnp.where(valid, tile_s, -jnp.inf)
         ids = jnp.where(valid, ids, BIG_ID)
@@ -752,8 +789,8 @@ def _fused_gathered_quantized_kernel(
 
     @pl.when(jnp.logical_and(j == n_j - 1, k == n_k - 1))
     def _flush():
-        s_out_ref[...] = rs_ref[...]
-        i_out_ref[...] = ri_ref[...]
+        s_out_ref[0] = rs_ref[...]
+        i_out_ref[0] = ri_ref[...]
 
 
 @functools.partial(
@@ -807,13 +844,17 @@ def fused_topk_gathered_quantized(
             common.pad_dim(docs, 1, bn, value=INT4_PAD_BYTE),
             2, bk // 2, value=INT4_PAD_BYTE,
         )
-        sp = common.pad_dim(common.pad_dim(scale, 1, bn), 2, bk // group)
+        sp = jnp.swapaxes(
+            common.pad_dim(common.pad_dim(scale, 1, bn), 2, bk // group), 1, 2
+        )
         assert 2 * dp.shape[2] == qp.shape[1], (dp.shape, qp.shape)
+        qp = _int4_query(qp, bk)
         d_spec = pl.BlockSpec((1, bn, bk // 2), lambda i, j, k: (i, j, k))
-        s_spec = pl.BlockSpec((1, bn, bk // group), lambda i, j, k: (i, j, k))
+        s_spec = pl.BlockSpec((1, bk // group, bn), lambda i, j, k: (i, k, j))
     rp = common.pad_dim(row_ids.astype(jnp.int32), 1, bn, value=BIG_ID)
     dpad = _depth_pad(depth, merge)
     grid = (b, dp.shape[1] // bn, qp.shape[1] // bk)
+    q_spec, rid_spec, out_specs, out_shape = _gathered_specs(b, bn, bk, dpad)
 
     scores, ids = pl.pallas_call(
         functools.partial(
@@ -822,30 +863,19 @@ def fused_topk_gathered_quantized(
             merge=merge, bits=bits, group=group,
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, k: (i, k)),
-            d_spec,
-            s_spec,
-            pl.BlockSpec((1, bn), lambda i, j, k: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, dpad), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, dpad), lambda i, j, k: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, dpad), jnp.float32),
-            jax.ShapeDtypeStruct((b, dpad), jnp.int32),
-        ],
+        in_specs=[q_spec, d_spec, s_spec, rid_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            common.MemorySpace.VMEM((1, bn), jnp.float32),
-            common.MemorySpace.VMEM((1, dpad), jnp.float32),
-            common.MemorySpace.VMEM((1, dpad), jnp.int32),
+            pltpu.MemorySpace.VMEM((1, bn), jnp.float32),
+            pltpu.MemorySpace.VMEM((1, dpad), jnp.float32),
+            pltpu.MemorySpace.VMEM((1, dpad), jnp.int32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(qp, dp, sp, rp)
-    scores = scores[:, :depth]
-    ids = ids[:, :depth]
+    )(qp[:, None, :], dp, sp, rp[:, None, :])
+    scores = scores[:, 0, :depth]
+    ids = ids[:, 0, :depth]
     return scores, jnp.where(scores == -jnp.inf, -1, ids)

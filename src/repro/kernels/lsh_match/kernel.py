@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
@@ -70,8 +71,8 @@ def lsh_match_scores(
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qp.shape[0], dp.shape[0]), jnp.int32),
-        scratch_shapes=[common.MemorySpace.VMEM((bq, bn), jnp.int32)],
-        compiler_params=common.CompilerParams(
+        scratch_shapes=[pltpu.MemorySpace.VMEM((bq, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

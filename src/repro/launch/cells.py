@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.common import ArchSpec, Cell
 from repro.core import distributed as ann_dist
 from repro.core.types import FakeWordsIndex
@@ -59,7 +58,7 @@ class CellBuild:
     def lower(self):
         # Mesh context: the step fns constrain activations with bare
         # PartitionSpecs (models don't hold mesh objects).
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self.jitted().lower(*self.args)
 
 
@@ -577,7 +576,7 @@ _BUILDERS = {
 
 def build_cell(arch: ArchSpec, cell: Cell, mesh: Mesh, multi_pod: bool,
                **kw) -> CellBuild:
-    with compat.set_mesh(mesh):  # builders eval_shape through constrained fns
+    with jax.set_mesh(mesh):  # builders eval_shape through constrained fns
         built = _BUILDERS[arch.family](arch, cell, mesh, multi_pod, **kw)
     built.mesh = mesh
     return built

@@ -20,7 +20,7 @@ import time
 import traceback
 
 
-from repro import compat, configs
+from repro import configs
 from repro.launch import cells as cells_mod
 from repro.launch import hlo_collectives
 from repro.launch.mesh import make_production_mesh
@@ -42,7 +42,7 @@ def run_cell(arch_id: str, cell_name: str, multi_pod: bool, keep_text: bool = Fa
     }
     t0 = time.time()
     built = cells_mod.build_cell(arch, cell, mesh, multi_pod)
-    with compat.set_mesh(mesh):  # context for bare-PartitionSpec constraints
+    with jax.set_mesh(mesh):  # context for bare-PartitionSpec constraints
         lowered = built.lower()
         rec["lower_s"] = round(time.time() - t0, 2)
         t1 = time.time()

@@ -9,12 +9,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.distributed import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds a leading pod=2 axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(*, multi_pod: bool = False):
@@ -23,6 +25,6 @@ def make_debug_mesh(*, multi_pod: bool = False):
     n = len(jax.devices())
     if multi_pod:
         assert n >= 8
-        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        return make_mesh((2, 2, 2), ("pod", "data", "model"))
     assert n >= 4
-    return jax.make_mesh((2, 2), ("data", "model"))
+    return make_mesh((2, 2), ("data", "model"))

@@ -38,7 +38,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import bruteforce, eval as ev
+from repro import compile_cache
+from repro.core import bruteforce, distributed, eval as ev
 from repro.core.index import AnnIndex
 from repro.core.segments import IndexWriter
 from repro.core.types import (
@@ -414,6 +415,7 @@ def main(argv=None) -> dict:
              "fusion next to each retriever alone",
     )
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     corpus = embeddings.make_corpus(
         embeddings.CorpusConfig(n_vectors=args.n_docs, dim=args.dim)
@@ -466,7 +468,7 @@ def main(argv=None) -> dict:
                 f"found {n_dev}; on CPU set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={args.shards}"
             )
-        mesh = jax.make_mesh((args.shards,), ("data",))
+        mesh = distributed.make_mesh((args.shards,), ("data",))
 
     config = make_config(args)
     rerank_store = "int8" if args.quantized_rerank else (

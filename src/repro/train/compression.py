@@ -20,8 +20,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 Pytree = Any
 
 
@@ -38,7 +36,7 @@ def compressed_psum(
     grads: Pytree, residual: Pytree, axis_name: str
 ) -> Tuple[Pytree, Pytree]:
     """Inside shard_map: returns (mean-reduced grads, new residual)."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         g32 = g.astype(jnp.float32) + r

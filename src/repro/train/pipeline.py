@@ -26,7 +26,6 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro import compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 Pytree = Any
@@ -114,7 +113,7 @@ def build_gpipe_fn(
         return gpipe_apply(layer_fn, params_stacked, x, n_stages, axis)
 
     in_specs = (P(), P(None, batch_axes if batch_axes else None))
-    return compat.shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs,
         out_specs=P(None, batch_axes if batch_axes else None),
         check_vma=False,

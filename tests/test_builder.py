@@ -51,7 +51,6 @@ def run_subprocess(body: str):
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro import compat
         """
     ) + textwrap.dedent(body)
     r = subprocess.run(

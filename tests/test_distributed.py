@@ -21,7 +21,7 @@ def run_subprocess(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro import compat
+        from repro.core.distributed import make_mesh
         """
     ) + textwrap.dedent(body)
     r = subprocess.run(
@@ -157,7 +157,7 @@ def test_sharded_gnn_full_graph_equals_single_device():
     params = gnn.init_params(jax.random.key(0), cfg)
     mask = jnp.ones((200,), jnp.float32)
     ref = gnn.loss_full(params, g.feats, src, dst, g.labels, mask, cfg)
-    mesh = jax.make_mesh((8,), ("dev",))
+    mesh = make_mesh((8,), ("dev",))
     # shard edges over all devices (uneven 800/8 is fine)
     es = NamedSharding(mesh, P("dev"))
     srcs = jax.device_put(src, es); dsts = jax.device_put(dst, es)
@@ -177,7 +177,7 @@ def test_sharded_recsys_table_equals_single_device():
     rows = np.asarray(table_spec.row_counts)
     idx = jnp.asarray(rng.integers(0, rows[None, :, None], (16, 8, 1)), jnp.int32)
     ref = rec.forward(params, cfg, idx)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     p_sh = dict(params)
     p_sh["table"] = jax.device_put(params["table"], NamedSharding(mesh, P("model", None)))
     p_sh["linear"] = jax.device_put(params["linear"], NamedSharding(mesh, P("model", None)))
@@ -197,14 +197,14 @@ def test_sharded_lm_train_step_equals_single_device():
     params = tfm.init_params(jax.random.key(0), cfg)
     toks = jax.random.randint(jax.random.key(1), (8, 16), 0, 128)
     ref = tfm.loss_fn(params, toks, toks, cfg)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg_sh = dataclasses.replace(cfg, batch_axes=("data",), tp_axis="model")
     from repro.sharding import rules
     specs = rules.lm_param_specs(tfm.param_shapes(cfg))
     p_sh = jax.tree.map(lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
                         params, specs, is_leaf=lambda x: hasattr(x, "shape"))
     t_sh = jax.device_put(toks, NamedSharding(mesh, P("data", None)))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, t: tfm.loss_fn(p, t, t, cfg_sh))(p_sh, t_sh)
     np.testing.assert_allclose(float(out), float(ref), rtol=2e-4)
     print("lm sharded loss ok", float(out), float(ref))
@@ -218,7 +218,7 @@ def test_compressed_allreduce_and_gpipe():
     g = jnp.arange(8 * 64, dtype=jnp.float32).reshape(8, 64) / 100.0
     def f(gs, r):
         return compression.compressed_psum(gs, r, "data")
-    out, new_r = jax.jit(compat.shard_map(
+    out, new_r = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P("data"), P("data")),
         out_specs=(P(), P("data"))))({"w": g}, {"w": jnp.zeros((8, 64))})
     exact = jnp.mean(g, axis=0)

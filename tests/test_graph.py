@@ -192,7 +192,6 @@ def test_graph_sharded_build_parity():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.core import distributed
         from repro.core.graph import build_graph
         from repro.core.types import GraphConfig

@@ -137,7 +137,7 @@ def test_vmem_evaluator_tile_clamps_and_scratch():
             _kern,
             in_specs=[pl.BlockSpec((bq, 128), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((bq, 128), lambda i: (i, 0)),
-            scratch_shapes=[common.MemorySpace.VMEM((bq, 128), jnp.float32)],
+            scratch_shapes=[pltpu.MemorySpace.VMEM((bq, 128), jnp.float32)],
         )(x)
     """
     cfg_pass = Config(vmem_budget_bytes=64 * 1024)
