@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.types import (
     BruteForceConfig,
     FakeWordsConfig,
@@ -299,9 +300,10 @@ class ExecutableCache:
             self._entries.move_to_end(full_key)
             self.hits += 1
             return hit
-        exe = jax.jit(build_fn(), donate_argnums=donate_argnums).lower(
-            *args
-        ).compile()
+        with obs.span("packed.compile", kind=key[0]):
+            exe = jax.jit(build_fn(), donate_argnums=donate_argnums).lower(
+                *args
+            ).compile()
         self.compiles += 1
         self._entries[full_key] = exe
         while len(self._entries) > self.capacity:
@@ -425,12 +427,13 @@ def _try_append(
         return None
 
     def build():
-        def append(old, new, off):
+        # Named for the trace: the executable's module is jit_packed_append.
+        def packed_append(old, new, off):
             return tuple(
                 jax.lax.dynamic_update_slice_in_dim(o, nw, off, axis=0)
                 for o, nw in zip(old, new)
             )
-        return append
+        return packed_append
 
     off_dev = jnp.int32(offset)
     exe = EXEC_CACHE.get(
@@ -478,19 +481,22 @@ def pack_segments(
     n_rows = sum(rows)
     bucket = bucket_rows(n_rows)
     if prior is not None and prior.view is not None:
-        inc = _try_append(
-            config, views, segments, prior, names, rows, bucket, n_rows
-        )
+        with obs.span("packed.append", rows=n_rows - prior.n_rows,
+                      segments=len(segments)):
+            inc = _try_append(
+                config, views, segments, prior, names, rows, bucket, n_rows
+            )
         if inc is not None:
             return inc
-    view = _packed_view(config, views, bucket)
-    return PackedSegments(
-        view=view, bucket=bucket, n_rows=n_rows,
-        n_live=sum(s.num_live for s in segments),
-        live=_live_bitmap(segments, n_rows, bucket),
-        any_deleted=any(s.del_count for s in segments),
-        seg_names=names, seg_rows=rows,
-    )
+    with obs.span("packed.pack", rows=n_rows, segments=len(segments)):
+        view = _packed_view(config, views, bucket)
+        return PackedSegments(
+            view=view, bucket=bucket, n_rows=n_rows,
+            n_live=sum(s.num_live for s in segments),
+            live=_live_bitmap(segments, n_rows, bucket),
+            any_deleted=any(s.del_count for s in segments),
+            seg_names=names, seg_rows=rows,
+        )
 
 
 # --------------------------------------------------------------------------
@@ -581,7 +587,8 @@ def packed_search(
         fm_arg = _pad_mask_cols(jnp.asarray(fm), bucket)
 
     def build():
-        def fn(view, live, fm_in, q_rep_in, q_norm_in, bm_in):
+        # Named for the trace: the executable's module is jit_packed_search.
+        def packed_search(view, live, fm_in, q_rep_in, q_norm_in, bm_in):
             filt = None
             if use_filt:
                 filt = live if fm_in is None else (
@@ -613,7 +620,7 @@ def packed_search(
                 out_s, pos = jax.lax.top_k(rs, k_out)
                 return out_s, jnp.take_along_axis(i, pos, axis=-1)
             return s[:, :k_out], i[:, :k_out]
-        return fn
+        return packed_search
 
     args = (pk.view, pk.live, fm_arg, q_rep, q_norm, bm)
     key = (

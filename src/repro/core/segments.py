@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bruteforce, builder, pca
 from repro.core import index as index_mod
 from repro.core import packed as packed_mod
@@ -992,19 +993,23 @@ class IndexWriter:
         True when a segment was written."""
         if not self._buf:
             return False
-        rows = np.concatenate(self._buf, axis=0)
-        live = np.concatenate(self._buf_live, axis=0)
-        md = _concat_metadata(self._buf_md)
-        ann = self._build_segment(jnp.asarray(rows), normalized=False, metadata=md)
-        self._segments.append(
-            Segment(
-                ann=ann, live=live, name=self._next_name(),
-                source=self._source_sidecar(ann, rows, normalized=False),
+        with obs.span("writer.flush", rows=self.buffered_docs,
+                      segments=len(self._segments)):
+            rows = np.concatenate(self._buf, axis=0)
+            live = np.concatenate(self._buf_live, axis=0)
+            md = _concat_metadata(self._buf_md)
+            ann = self._build_segment(
+                jnp.asarray(rows), normalized=False, metadata=md
             )
-        )
-        self._buf, self._buf_live, self._buf_md = [], [], []
-        self._changed = True
-        self.maybe_merge()
+            self._segments.append(
+                Segment(
+                    ann=ann, live=live, name=self._next_name(),
+                    source=self._source_sidecar(ann, rows, normalized=False),
+                )
+            )
+            self._buf, self._buf_live, self._buf_md = [], [], []
+            self._changed = True
+            self.maybe_merge()
         return True
 
     def _build_segment(
@@ -1103,26 +1108,28 @@ class IndexWriter:
         and return a point-in-time snapshot.  The epoch advances IFF
         something changed; an unchanged refresh returns the cached reader,
         so epoch-keyed serving caches stay warm."""
-        self.flush()
-        if self._reader is None or self._changed:
-            old = self._reader
-            self._reader = SegmentedAnnIndex(
-                self.config,
-                [s.snapshot() for s in self._segments],
-                use_kernel=self.use_kernel,
-                global_stats=self.global_stats,
-            )
-            if old is not None and packed_mod.stats_static(self.config):
-                # Hand the old snapshot's packed buffers to the new reader:
-                # an append-only refresh absorbs them via a donated
-                # incremental repack (core/packed.py).  The old reader
-                # lazily repacks if searched again after donation.  Other
-                # encodings repack fully, so the old buffers stay with the
-                # old reader and are freed with it, before the new pack.
-                self._reader._packed_prior = old._packed
-                old._packed = None
-            self._changed = False
-        return self._reader
+        with obs.span("writer.refresh", rows=self.total_docs,
+                      segments=len(self._segments)):
+            self.flush()
+            if self._reader is None or self._changed:
+                old = self._reader
+                self._reader = SegmentedAnnIndex(
+                    self.config,
+                    [s.snapshot() for s in self._segments],
+                    use_kernel=self.use_kernel,
+                    global_stats=self.global_stats,
+                )
+                if old is not None and packed_mod.stats_static(self.config):
+                    # Hand the old snapshot's packed buffers to the new reader:
+                    # an append-only refresh absorbs them via a donated
+                    # incremental repack (core/packed.py).  The old reader
+                    # lazily repacks if searched again after donation.  Other
+                    # encodings repack fully, so the old buffers stay with the
+                    # old reader and are freed with it, before the new pack.
+                    self._reader._packed_prior = old._packed
+                    old._packed = None
+                self._changed = False
+            return self._reader
 
     def commit(self, path: Optional[str] = None) -> int:
         """Flush + durably persist a generation-numbered commit point.

@@ -30,9 +30,11 @@ serve another index generation's cached results.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import hashlib
+import itertools
 import queue as queue_mod
 import threading
 import time
@@ -43,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.core import bruteforce, distributed
 from repro.core import packed as packed_mod
 from repro.core import pipeline as pl
@@ -75,8 +78,6 @@ class AnnServiceConfig:
     # superbuffer, docs/DESIGN.md §14).
     blockmax_keep: Optional[int] = None
     blockmax_block_size: int = 256
-    # Latency ring-buffer length for stats() p50/p99 (per-batch wall times).
-    latency_window: int = 1024
     # Per-shard result cache (ROADMAP follow-up): LRU over the last
     # ``cache_size`` micro-batches, keyed on the hash of the ENCODED query
     # representation bytes + the effective SearchParams/knobs + the index
@@ -84,6 +85,14 @@ class AnnServiceConfig:
     # query stream skips the match+rerank entirely on this serving shard.
     # 0 disables.  Hit/miss counters surface in stats().
     cache_size: int = 0
+
+
+def _pad_rows(x: np.ndarray, rows: int) -> np.ndarray:
+    """``x`` with zero rows appended up to ``rows``."""
+    pad = rows - x.shape[0]
+    if not pad:
+        return x
+    return np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)], 0)
 
 
 class AnnService:
@@ -127,11 +136,18 @@ class AnnService:
         self._bind(ann)
         self.queries_served = 0
         self.batches = 0
-        self._lat_s = collections.deque(maxlen=self.scfg.latency_window)
-        # Per-REQUEST enqueue->result wall times for the async path; kept
-        # apart from the per-batch ring so SLO percentiles are honest
-        # (queue wait included, batch fan-in not averaged away).
-        self._req_lat_s = collections.deque(maxlen=self.scfg.latency_window)
+        # Per-launch wall times (stats() lat_*), and per-REQUEST
+        # enqueue->result times on the async path (req_*), kept apart so
+        # SLO percentiles are honest (queue wait included, batch fan-in not
+        # averaged away).
+        self._lat = obs.LatencyHistogram()
+        self._req_lat = obs.LatencyHistogram()
+        # Async requests' waits from enqueue to the start of their launch.
+        self.queue_wait_s = 0.0
+        self.async_requests = 0
+        # Ids that tie a request's or a launch's spans together.
+        self._req_ids = itertools.count()
+        self._launch_ids = itertools.count()
         self._cache: "collections.OrderedDict[bytes, Tuple[np.ndarray, np.ndarray]]" = (
             collections.OrderedDict()
         )
@@ -302,6 +318,8 @@ class AnnService:
         queries: np.ndarray,
         filter: Optional[np.ndarray] = None,
         plan=None,
+        reqs: Sequence[Tuple[int, int, float]] = (),
+        launch: Optional[int] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """(B, dim) -> (scores (B,k), ids (B,k)); pads to max_batch so the
         jit cache holds exactly one entry.
@@ -319,15 +337,23 @@ class AnnService:
         FusionStage / MultiVectorPlan / QueryPlan) run as ONE batch in
         place of this service's own index search; sub-plan leaves carry
         their own filters and indexes.  Plan results bypass the result
-        cache (a plan's identity isn't hashable state)."""
+        cache (a plan's identity isn't hashable state).
+
+        ``reqs`` and ``launch`` come from the async micro-batcher: the
+        requests whose rows ``queries`` holds, in row order, as (request
+        id, rows, enqueue time), and the id of the batch's first launch.
+        They name the launches in a profiler trace and feed
+        ``queue_wait_s``."""
         with self._lock:
-            return self._search_batch(queries, filter, plan)
+            return self._search_batch(queries, filter, plan, reqs, launch)
 
     def _search_batch(
         self,
         queries: np.ndarray,
         filter: Optional[np.ndarray] = None,
         plan=None,
+        reqs: Sequence[Tuple[int, int, float]] = (),
+        launch: Optional[int] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
         b = queries.shape[0]
         if plan is not None:
@@ -335,98 +361,118 @@ class AnnService:
                 raise ValueError(
                     "pass filters on the plan's leaves, not alongside plan="
                 )
-            t0 = time.perf_counter()
-            s, ids = plan.run(jnp.asarray(queries))
-            # Result hand-off: callers take numpy.
-            s_np, i_np = np.asarray(s), np.asarray(ids)  # reprolint: disable=hostsync
-            self.batches += 1
-            self._lat_s.append(time.perf_counter() - t0)
+            lid = next(self._launch_ids) if launch is None else launch
+            with obs.span("ann.launch", launch=lid, rows=b):
+                t0 = time.perf_counter()
+                with obs.span("ann.dispatch", launch=lid):
+                    s, ids = plan.run(jnp.asarray(queries))
+                # Result hand-off: callers take numpy.
+                with obs.span("ann.handoff", launch=lid):
+                    s_np, i_np = np.asarray(s), np.asarray(ids)  # reprolint: disable=hostsync
+                self.batches += 1
+                self._lat.add(time.perf_counter() - t0)
             self.queries_served += b
             return s_np, i_np
         mb = self.scfg.max_batch
-        pad = (-b) % mb
-        if pad:
-            queries = np.concatenate(
-                [queries, np.zeros((pad, queries.shape[1]), queries.dtype)], 0
-            )
         fm = None
         if filter is not None:
             # Host-side caller input (predicate bitmap), not a device array.
             fm = np.asarray(filter)  # reprolint: disable=hostsync
-            if fm.ndim == 2:
-                if self.mesh is not None:
-                    raise ValueError(
-                        "sharded filtered serving takes a shared (N,) mask "
-                        "(it shards with the postings); per-query (B, N) "
-                        "masks are single-device/segmented only"
-                    )
-                if pad:
+            if fm.ndim == 2 and self.mesh is not None:
+                raise ValueError(
+                    "sharded filtered serving takes a shared (N,) mask "
+                    "(it shards with the postings); per-query (B, N) "
+                    "masks are single-device/segmented only"
+                )
+        use_cache = self.scfg.cache_size > 0
+        # First row of each async request: a launch counts the queue wait
+        # of the requests whose first row it carries.
+        starts = list(itertools.accumulate((r[1] for r in reqs), initial=0))
+        charged = 0
+        out_s, out_i = [], []
+        for i in range(0, b, mb):
+            end = min(i + mb, b)
+            lid = launch if i == 0 and launch is not None else next(self._launch_ids)
+            ids_of_reqs = {}
+            if reqs:
+                last = bisect.bisect_left(starts, end) - 1
+                ids_of_reqs = dict(
+                    first_req=reqs[bisect.bisect_right(starts, i) - 1][0],
+                    last_req=reqs[last][0],
+                )
+            with obs.span("ann.launch", launch=lid, rows=end - i, **ids_of_reqs):
+                t0 = time.perf_counter()
+                if reqs:
+                    self.queue_wait_s += sum(t0 - r[2] for r in reqs[charged : last + 1])
+                    self.async_requests += last + 1 - charged
+                    charged = last + 1
+                with obs.span("ann.dispatch", launch=lid):
                     # Padded queries get all-zero mask rows; their padded
                     # (-inf, -1) results are trimmed with the batch below.
-                    fm = np.concatenate(
-                        [fm, np.zeros((pad, fm.shape[1]), fm.dtype)], 0
-                    )
-        use_cache = self.scfg.cache_size > 0
-        out_s, out_i = [], []
-        for i in range(0, queries.shape[0], mb):
-            t0 = time.perf_counter()
-            q_np = queries[i : i + mb]
-            fl = fm if fm is None or fm.ndim == 1 else fm[i : i + mb]
-            fl_dev = jnp.asarray(fl) if fl is not None else None
-            if self._segmented:
-                # The segmented reader encodes per search (its global-stats
-                # view owns any fitted model), so key on the raw query
-                # bytes; the epoch in the key still pins the snapshot.
-                key = self._cache_key(q_np, None, fl) if use_cache else None
-                q = q_rep = None
-            else:
-                q = bruteforce.l2_normalize(jnp.asarray(q_np))
-                q_rep = self.ann.pipeline.encoder(self.ann.index, q)
-                key = self._cache_key(q_rep, q, fl) if use_cache else None
-            if use_cache and key in self._cache:
-                self._cache.move_to_end(key)
-                s_np, i_np = self._cache[key]
-                self.cache_hits += 1
-            else:
-                if self._segmented:
-                    s, ids = self.ann.search(
-                        jnp.asarray(q_np), k=self.scfg.k,
-                        depth=self.scfg.depth, rerank=self.scfg.rerank,
-                        use_kernel=self._uk, filter_mask=fl_dev,
-                        blockmax_keep=self._bm_keep,
-                        blockmax_block_size=self._bm_block,
-                    )
-                elif self._search is not None:
-                    args = (self.ann.index,) + (
-                        (self._bm,) if self._bm is not None else ()
-                    ) + (q_rep, q)
-                    if fl_dev is not None:
-                        s, ids = self._search_filtered(*args, fl_dev)
+                    q_np = _pad_rows(queries[i:end], mb)
+                    fl = fm if fm is None or fm.ndim == 1 else _pad_rows(fm[i:end], mb)
+                    fl_dev = jnp.asarray(fl) if fl is not None else None
+                    if self._segmented:
+                        # The segmented reader encodes per search (its
+                        # global-stats view owns any fitted model), so key
+                        # on the raw query bytes; the epoch in the key
+                        # still pins the snapshot.
+                        key = self._cache_key(q_np, None, fl) if use_cache else None
+                        q = q_rep = None
                     else:
-                        s, ids = self._search(*args)
+                        q = bruteforce.l2_normalize(jnp.asarray(q_np))
+                        q_rep = self.ann.pipeline.encoder(self.ann.index, q)
+                        key = self._cache_key(q_rep, q, fl) if use_cache else None
+                    hit = self._cache.get(key) if use_cache else None
+                    if hit is None:
+                        s, ids = self._run_search(q_np, fl_dev, q, q_rep)
+                if hit is not None:
+                    self._cache.move_to_end(key)
+                    s_np, i_np = hit
+                    self.cache_hits += 1
                 else:
-                    s, ids = pl.match_rerank(
-                        self._matcher(), self.ann.index, q_rep, q,
-                        self.scfg.k, self.scfg.depth, self.scfg.rerank,
-                        bm=self._bm, use_kernel=self._uk,
-                        reranker=self.ann.pipeline.reranker,
-                        filt=fl_dev,
-                    )
-                # Hand-off point: blocking here keeps device compute inside
-                # the wall time recorded below.
-                s_np = np.asarray(s)   # reprolint: disable=hostsync
-                i_np = np.asarray(ids)  # reprolint: disable=hostsync
-                if use_cache:
-                    self.cache_misses += 1
-                    self._cache[key] = (s_np, i_np)
-                    while len(self._cache) > self.scfg.cache_size:
-                        self._cache.popitem(last=False)
-            out_s.append(s_np)
-            out_i.append(i_np)
-            self.batches += 1
-            self._lat_s.append(time.perf_counter() - t0)
+                    # Hand-off point: blocking here keeps device compute
+                    # inside the wall time recorded below.
+                    with obs.span("ann.handoff", launch=lid):
+                        s_np = np.asarray(s)   # reprolint: disable=hostsync
+                        i_np = np.asarray(ids)  # reprolint: disable=hostsync
+                    if use_cache:
+                        self.cache_misses += 1
+                        self._cache[key] = (s_np, i_np)
+                        while len(self._cache) > self.scfg.cache_size:
+                            self._cache.popitem(last=False)
+                out_s.append(s_np)
+                out_i.append(i_np)
+                self.batches += 1
+                self._lat.add(time.perf_counter() - t0)
         self.queries_served += b
         return np.concatenate(out_s)[:b], np.concatenate(out_i)[:b]
+
+    def _run_search(self, q_np, fl_dev, q, q_rep):
+        """Dispatch one padded chunk through the served index: segmented
+        (the reader normalises and encodes), sharded, or single-device."""
+        if self._segmented:
+            return self.ann.search(
+                jnp.asarray(q_np), k=self.scfg.k,
+                depth=self.scfg.depth, rerank=self.scfg.rerank,
+                use_kernel=self._uk, filter_mask=fl_dev,
+                blockmax_keep=self._bm_keep,
+                blockmax_block_size=self._bm_block,
+            )
+        if self._search is not None:
+            args = (self.ann.index,) + (
+                (self._bm,) if self._bm is not None else ()
+            ) + (q_rep, q)
+            if fl_dev is not None:
+                return self._search_filtered(*args, fl_dev)
+            return self._search(*args)
+        return pl.match_rerank(
+            self._matcher(), self.ann.index, q_rep, q,
+            self.scfg.k, self.scfg.depth, self.scfg.rerank,
+            bm=self._bm, use_kernel=self._uk,
+            reranker=self.ann.pipeline.reranker,
+            filt=fl_dev,
+        )
 
     # ``search`` is the public name (filter= / plan= per docs/DESIGN.md
     # §13); ``search_batch`` predates it and stays as the primary def.
@@ -486,72 +532,88 @@ class AnnService:
             q = q[None, :]
         fkey = None if filter is None else np.asarray(filter).tobytes()  # reprolint: disable=hostsync
         fut: "Future[Tuple[np.ndarray, np.ndarray]]" = Future()
-        try:
-            self._queue.put_nowait((q, filter, fkey, fut, time.perf_counter()))
-        except queue_mod.Full:
-            # Admission counters are bumped from arbitrary caller threads;
-            # without the lock, concurrent += drops increments.
-            with self._lock:
-                self.rejected += 1
-            raise
+        rid = next(self._req_ids)
+        with obs.span("ann.enqueue", req=rid):
+            try:
+                self._queue.put_nowait(
+                    (q, filter, fkey, fut, time.perf_counter(), rid)
+                )
+            except queue_mod.Full:
+                # Admission counters are bumped from arbitrary caller
+                # threads; without the lock, concurrent += drops increments.
+                with self._lock:
+                    self.rejected += 1
+                raise
         return fut
 
     def _batch_loop(self) -> None:
         carry = None
         while True:
-            req = carry if carry is not None else self._queue.get()
-            carry = None
+            if carry is None:
+                with obs.span("ann.queue_wait"):
+                    req = self._queue.get()
+            else:
+                req, carry = carry, None
             if req is None:
                 return
             if self._stop.is_set():
                 req[3].set_exception(RuntimeError("service stopped"))
                 continue
-            batch = [req]
-            rows = req[0].shape[0]
-            deadline = req[4] + self.scfg.max_wait_s
-            # Coalesce until max_batch rows or the OLDEST request's wait
-            # hits the window; only same-filter requests share a launch
-            # (one bitmap operand per batch).  Backlog already sitting in
-            # the queue coalesces unconditionally (it costs nothing and is
-            # what keeps throughput up when arrivals outrun launches);
-            # the deadline only governs how long to wait for MORE.
-            while rows < self.scfg.max_batch:
-                try:
-                    nxt = self._queue.get_nowait()
-                except queue_mod.Empty:
-                    wait = deadline - time.perf_counter()
-                    if wait <= 0:
-                        break
+            with obs.span("ann.coalesce") as coalesce:
+                batch = [req]
+                rows = req[0].shape[0]
+                deadline = req[4] + self.scfg.max_wait_s
+                # Coalesce until max_batch rows or the OLDEST request's wait
+                # hits the window; only same-filter requests share a launch
+                # (one bitmap operand per batch).  Backlog already sitting
+                # in the queue coalesces unconditionally (it costs nothing
+                # and is what keeps throughput up when arrivals outrun
+                # launches); the deadline only governs how long to wait for
+                # MORE.
+                while rows < self.scfg.max_batch:
                     try:
-                        nxt = self._queue.get(timeout=wait)
+                        nxt = self._queue.get_nowait()
                     except queue_mod.Empty:
+                        wait = deadline - time.perf_counter()
+                        if wait <= 0:
+                            break
+                        try:
+                            nxt = self._queue.get(timeout=wait)
+                        except queue_mod.Empty:
+                            break
+                    if nxt is None or self._stop.is_set():
+                        carry = nxt
                         break
-                if nxt is None or self._stop.is_set():
-                    carry = nxt
-                    break
-                if nxt[2] != req[2]:
-                    carry = nxt  # different filter: next launch
-                    break
-                batch.append(nxt)
-                rows += nxt[0].shape[0]
+                    if nxt[2] != req[2]:
+                        carry = nxt  # different filter: next launch
+                        break
+                    batch.append(nxt)
+                    rows += nxt[0].shape[0]
+                launch = next(self._launch_ids)
+                coalesce.set_metadata(launch=launch, rows=rows)
             try:
                 qs = np.concatenate([r[0] for r in batch], axis=0)
-                s, ids = self.search_batch(qs, filter=req[1])
-                done = time.perf_counter()
-                # Stats are read by caller threads (stats()/reset_latency()
-                # hold the lock); mutate them under it too.  Future
-                # resolution stays OUTSIDE the lock: set_result runs done-
-                # callbacks on this thread, and a callback that re-enters
-                # the service must not find the lock held.
-                with self._lock:
-                    self.async_launches += 1
+                s, ids = self.search_batch(
+                    qs, filter=req[1], launch=launch,
+                    reqs=[(r[5], r[0].shape[0], r[4]) for r in batch],
+                )
+                with obs.span("ann.resolve", launch=launch):
+                    done = time.perf_counter()
+                    # Stats are read by caller threads (stats()/
+                    # reset_latency() hold the lock); mutate them under it
+                    # too.  Future resolution stays OUTSIDE the lock:
+                    # set_result runs done-callbacks on this thread, and a
+                    # callback that re-enters the service must not find the
+                    # lock held.
+                    with self._lock:
+                        self.async_launches += 1
+                        for r in batch:
+                            self._req_lat.add(done - r[4])
+                    off = 0
                     for r in batch:
-                        self._req_lat_s.append(done - r[4])
-                off = 0
-                for r in batch:
-                    n = r[0].shape[0]
-                    r[3].set_result((s[off : off + n], ids[off : off + n]))
-                    off += n
+                        n = r[0].shape[0]
+                        r[3].set_result((s[off : off + n], ids[off : off + n]))
+                        off += n
             except Exception as e:  # propagate to every caller in the batch
                 for r in batch:
                     if not r[3].done():
@@ -562,21 +624,16 @@ class AnnService:
         whose wall time is orders of magnitude above steady state and would
         otherwise dominate the p99)."""
         with self._lock:
-            self._lat_s.clear()
-            self._req_lat_s.clear()
+            self._lat.clear()
+            self._req_lat.clear()
 
     @staticmethod
-    # Stats path: the ring holds Python floats from perf_counter, never
-    # device arrays — np.percentile here is pure host math.
-    # reprolint: disable=hostsync
-    def _pcts(ring) -> Tuple[Optional[float], Optional[float]]:
-        ms = np.asarray(ring, np.float64) * 1e3
-        if not ms.size:
+    def _pcts(hist: obs.LatencyHistogram) -> Tuple[Optional[float], Optional[float]]:
+        """(p50, p99) in ms, None when nothing was recorded."""
+        p50, p99 = hist.percentile(50), hist.percentile(99)
+        if p50 is None or p99 is None:
             return None, None
-        return (
-            round(float(np.percentile(ms, 50)), 3),
-            round(float(np.percentile(ms, 99)), 3),
-        )
+        return round(p50 * 1e3, 3), round(p99 * 1e3, 3)
 
     def _packed_stats(self) -> dict:
         """Observability for the packed single-launch path: process-wide
@@ -602,8 +659,11 @@ class AnnService:
         return out
 
     def stats(self) -> dict:
-        lat_p50, lat_p99 = self._pcts(self._lat_s)
-        req_p50, req_p99 = self._pcts(self._req_lat_s)
+        # Under the lock: a clear() between reading ``n`` and the counts
+        # would put the rank past every bucket.
+        with self._lock:
+            lat_p50, lat_p99 = self._pcts(self._lat)
+            req_p50, req_p99 = self._pcts(self._req_lat)
         return {
             "queries": self.queries_served,
             "batches": self.batches,
@@ -612,7 +672,7 @@ class AnnService:
             "method": self.ann.method,
             "epoch": getattr(self.ann, "epoch", None),
             "segments": getattr(self.ann, "num_segments", None),
-            # Per-BATCH device wall times (one search_batch call each).
+            # Per-launch wall times (one max_batch chunk each).
             "lat_p50_ms": lat_p50,
             "lat_p99_ms": lat_p99,
             # Per-REQUEST enqueue->result times on the async path: queue
@@ -621,6 +681,8 @@ class AnnService:
             "req_p50_ms": req_p50,
             "req_p99_ms": req_p99,
             "async_launches": self.async_launches,
+            "async_requests": self.async_requests,
+            "queue_wait_s": self.queue_wait_s,
             "rejected": self.rejected,
             "queue_depth": self._queue.qsize() if self._queue is not None else 0,
             "cache_hits": self.cache_hits,

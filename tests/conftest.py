@@ -18,14 +18,17 @@ if _REPO_ROOT not in sys.path:
 from tools.reprolint.trace_audit import trace_audit  # noqa: E402,F401
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture
 def rng():
+    """A fresh generator per test, so a test's data does not depend on which
+    tests ran before it in the same worker."""
     return np.random.default_rng(0)
 
 
 @pytest.fixture(scope="session")
-def small_corpus(rng):
+def small_corpus():
     """(2000, 64) unit-ish vectors with a planted mean component."""
+    rng = np.random.default_rng(0)
     x = rng.normal(size=(2000, 64)).astype(np.float32)
     x += 0.5 * rng.normal(size=(1, 64)).astype(np.float32)
     return x

@@ -192,6 +192,25 @@ def test_donated_incremental_append(rng):
     _assert_packed_equals_loop(r0, queries)
 
 
+def test_packed_executables_have_stable_names(rng):
+    """The packed search and the donated append compile as modules named
+    ``jit_packed_search`` and ``jit_packed_append``, so a profiler trace
+    names them the same after any refactor of their bodies."""
+    cfg = LexicalLshConfig(buckets=64, hashes=2)
+    w = _writer(cfg, "fp32", "exact", 1, rng, seg_docs=600)
+    r0 = w.refresh()
+    queries = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
+    r0.search(queries, k=5, depth=20, packed=True)
+    w.add(rng.normal(size=(20, 32)).astype(np.float32))
+    r1 = w.refresh()
+    assert r1.packed_segments().appends == 1
+    for kind in ("search", "append"):
+        exes = packed_mod.EXEC_CACHE.executables(kind)
+        assert exes
+        for exe in exes:
+            assert exe.as_text().startswith(f"HloModule jit_packed_{kind},")
+
+
 def test_classic_repacks_fully_and_stays_exact(rng):
     """Classic scoring rebuilds per-row state under new global idf, so a
     refresh must NOT incrementally append — and stays loop-exact."""

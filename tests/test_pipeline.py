@@ -90,13 +90,14 @@ def test_ann_service_latency_stats(small_corpus):
     v = jnp.asarray(small_corpus)
     svc = AnnService(
         AnnIndex.build(v, FakeWordsConfig(quantization=50)),
-        AnnServiceConfig(k=10, depth=50, max_batch=8, latency_window=4),
+        AnnServiceConfig(k=10, depth=50, max_batch=8),
     )
     assert svc.stats()["lat_p50_ms"] is None  # nothing served yet
-    svc.search_batch(small_corpus[:48])  # 6 batches through a window of 4
+    buckets = len(svc._lat.counts)
+    svc.search_batch(small_corpus[:48])  # 6 launches, every one recorded
     stats = svc.stats()
-    assert stats["batches"] == 6
-    assert len(svc._lat_s) == 4  # ring buffer, not unbounded
+    assert stats["batches"] == 6 and svc._lat.n == 6
+    assert len(svc._lat.counts) == buckets  # fixed buckets, not a growing log
     assert stats["lat_p50_ms"] > 0 and stats["lat_p99_ms"] >= stats["lat_p50_ms"]
     svc.reset_latency()  # warmup exclusion hook: drops latencies, not counts
     assert svc.stats()["lat_p50_ms"] is None and svc.stats()["batches"] == 6

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bruteforce, eval as ev, fakewords
 from repro.core.types import FakeWordsConfig
 from repro.models import transformer as tfm
@@ -240,11 +241,12 @@ def test_ann_service_segmented_blockmax(small_corpus):
 
 def test_ann_service_stats_mutations_hold_lock(small_corpus):
     """Regression (reprolint rule ``lockdiscipline``): the worker thread
-    bumped ``async_launches`` and appended request latencies off-lock, and
+    bumped ``async_launches`` and recorded request latencies off-lock, and
     ``rejected`` / ``reset_latency`` mutated shared stats from caller
-    threads off-lock.  Instrument the lock and the mutation points, then
-    drive every path: any off-lock mutation is recorded as a violation."""
-    import collections
+    threads off-lock.  Instrument the lock and the mutation points (the
+    counters and both latency histograms) and the histograms' reads, then
+    drive every path: any off-lock mutation or read is recorded as a
+    violation."""
     import queue as queue_mod
     import threading
 
@@ -270,24 +272,29 @@ def test_ann_service_stats_mutations_hold_lock(small_corpus):
         def held(self):
             return getattr(self._local, "depth", 0) > 0
 
-    class GuardedDeque(collections.deque):
-        def __init__(self, name, lock, maxlen=None):
-            super().__init__(maxlen=maxlen)
+    class GuardedHistogram(obs.LatencyHistogram):
+        def __init__(self, name, lock):
+            super().__init__()
             self._name = name
             self._guard = lock
 
-        def append(self, x):
+        def add(self, seconds):
             if not self._guard.held:
-                violations.append(f"{self._name}.append")
-            super().append(x)
+                violations.append(f"{self._name}.add")
+            super().add(seconds)
 
         def clear(self):
             if not self._guard.held:
                 violations.append(f"{self._name}.clear")
             super().clear()
 
+        def percentile(self, q):
+            if not self._guard.held:
+                violations.append(f"{self._name}.percentile")
+            return super().percentile(q)
+
     guarded_ints = {"async_launches", "rejected", "batches",
-                    "queries_served"}
+                    "queries_served", "queue_wait_s", "async_requests"}
 
     class GuardedService(AnnService):
         def __setattr__(self, name, value):
@@ -304,8 +311,8 @@ def test_ann_service_stats_mutations_hold_lock(small_corpus):
         queue_depth=8))
     lock = CheckedLock()
     svc._lock = lock
-    svc._lat_s = GuardedDeque("_lat_s", lock)
-    svc._req_lat_s = GuardedDeque("_req_lat_s", lock)
+    svc._lat = GuardedHistogram("_lat", lock)
+    svc._req_lat = GuardedHistogram("_req_lat", lock)
     svc._armed = True
 
     svc.search_batch(small_corpus[:8])           # sync path
@@ -321,7 +328,8 @@ def test_ann_service_stats_mutations_hold_lock(small_corpus):
             except queue_mod.Full:
                 rejected += 1                    # rejection path
     svc.stop_async()
-    svc.reset_latency()                          # ring-clear path
+    svc.reset_latency()                          # histogram-clear path
     assert rejected >= 1
     assert svc.stats()["rejected"] == rejected
+    assert isinstance(svc._req_lat, GuardedHistogram) and svc._req_lat.n == 0
     assert violations == []
