@@ -166,10 +166,11 @@ def build_blockmax(
     if signed_store:
         s = tf.astype(jnp.int8)
     else:
-        m = tf.shape[1] // 2
-        s = (tf[:, :m].astype(jnp.int32) - tf[:, m:].astype(jnp.int32)).astype(
-            jnp.int8
-        )
+        # df spans the logical 2m columns; a packed tf may be lane-aligned.
+        m = index.df.shape[0] // 2
+        s = (
+            tf[:, :m].astype(jnp.int32) - tf[:, m : 2 * m].astype(jnp.int32)
+        ).astype(jnp.int8)
     ub = jnp.concatenate(
         [_block_reduce_max(s, block_size), _block_reduce_max(-s, block_size)],
         axis=-1,
@@ -182,7 +183,12 @@ def block_bounds(bm: BlockMaxIndex, q: jax.Array) -> jax.Array:
 
     ``q`` is the mode's match-phase query representation: the (B, 2m) tf row
     for classic AND dot (the dot bound's ``[relu(u); relu(-u)]`` operand IS
-    the sign-split encoding), or the (B, S) uint32 signature for lsh."""
+    the sign-split encoding), or the (B, S) uint32 signature for lsh.
+    Bounds built over a lane-aligned packed view are as wide as the stored
+    leaf; the query is widened to match (exact: zero or sentinel pads)."""
+    from repro.kernels.fused_topk import ops as fused
+
+    q = fused.pad_query(q, bm.ub.shape[-1])
     if bm.mode == "classic":
         return jnp.einsum(
             "bt,nt->bn", q.astype(jnp.bfloat16), bm.ub,
@@ -271,6 +277,8 @@ def pruned_topk(
     row_ids = keep_blocks[:, :, None] * bsz + jnp.arange(bsz)[None, None, :]
     row_ids = row_ids.reshape(b, -1).astype(jnp.int32)  # (B, n_keep*bsz)
     qv, mat, mode = _stage2_operands(index, bm, q)
+    if mode != "quantized":
+        qv = fused.pad_query(qv, mat.shape[-1])  # lane-aligned packed leaf
     if mode == "quantized":
         if fused.resolve_use_kernel(use_kernel):
             d_s, d_i = fused.postings_topk_gathered(

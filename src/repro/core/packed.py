@@ -13,6 +13,14 @@ of segment count:
     is the identity by construction and the kernel emits global ids
     directly.  Global leaves (df/idf, the fitted reduction) come from the
     stat views, which already share them across segments.
+  * **Lane-aligned leaves.**  Every per-doc leaf the search streams whole
+    (the match operand) or gathers rows from (the f32 rerank rows) is
+    stored at the fused kernels' reduce width
+    (``fused_topk.kernel.aligned_width``), padded with the filler the kernel
+    wrappers would pad with per call.  XLA then keeps it row-major, so a
+    call neither relays out nor pads the corpus; every consumer that reads
+    it without a kernel pass keeps the logical width by padding the query
+    (exact) or slicing the gathered candidates, never the corpus.
   * **Bucket ladder.**  Only the tail pads, up to a small geometric ladder
     (powers of two and 1.5x steps, ≤ 33% overhead), so executable shapes
     recur across flush/merge/refresh cycles instead of recompiling per
@@ -24,7 +32,9 @@ of segment count:
     executables (:class:`ExecutableCache`); the key is (static knobs,
     pytree structure, leaf avals), so refresh cycles within one bucket are
     zero-compile.  ``EXEC_CACHE.compiles`` makes the recompile-guard test
-    honest.
+    honest.  Each compile records the executable's temp bytes (on the
+    ``packed.compile`` span and, for the search, on the snapshot): a
+    whole-corpus relayout inside the search shows up there.
   * **Donated incremental repack.**  For stats-static encodings (dot-mode
     fake words, LSH, brute force) a refresh that only appends segments
     reuses the previous snapshot's packed buffers via a donated
@@ -119,17 +129,23 @@ def _append_block(n: int, room: int = 1 << 30, floor: int = 128) -> int:
 # --------------------------------------------------------------------------
 
 
-def _cat_pad(parts: Sequence[jax.Array], rows: int) -> jax.Array:
+def _cat_pad(
+    parts: Sequence[jax.Array], rows: int, align: bool = False
+) -> jax.Array:
     """Concatenate per-segment per-doc leaves along rows and zero-pad the
-    tail to ``rows``.  Zero padding is load-bearing: pad rows are masked at
-    search time, and the donated append path overwrites tail rows assuming
-    they hold zeros."""
+    tail to ``rows``.  Pad rows are masked at search time, and the donated
+    append path overwrites them.  ``align`` also widens the minor axis to
+    the fused kernels' reduce width, in the same pass."""
+    from repro.kernels.fused_topk import ops as fused
+
     x = parts[0] if len(parts) == 1 else jnp.concatenate(list(parts), axis=0)
     pad = rows - x.shape[0]
     if pad < 0:
         raise PackedUnsupported(
             f"segment rows {x.shape[0]} exceed bucket {rows}"
         )
+    if align:
+        return fused.lane_align(x, rows)
     if pad == 0:
         return x
     return jnp.concatenate(
@@ -177,20 +193,31 @@ def _pack_pq(views: Sequence[Any], rows: int) -> Optional[QuantizedPostings]:
 
 def _packed_view(config, views: Sequence[Any], rows: int):
     """One synthetic index view with every per-doc leaf packed to ``rows``;
-    global leaves (df/idf/reduction) carry over from the stat views."""
+    global leaves (df/idf/reduction) carry over from the stat views.  The
+    match operand and the f32 rerank rows are stored lane-aligned."""
+    if not isinstance(
+        config, (FakeWordsConfig, LexicalLshConfig, KdTreeConfig,
+                 BruteForceConfig)
+    ):
+        raise PackedUnsupported(
+            f"no packed layout for config type {type(config).__name__}"
+        )
     v0 = views[0]
     repl: Dict[str, Any] = {"vq": _pack_vq(views, rows)}
+    vecs = _all_or_none(views, "vectors")
+    repl["vectors"] = None if vecs is None else _cat_pad(vecs, rows, True)
     if isinstance(config, FakeWordsConfig):
         repl["pq"] = _pack_pq(views, rows)
         repl["norm"] = _cat_pad([v.norm for v in views], rows)
-        for name in ("tf", "scored", "vectors"):
+        match = "scored" if config.scoring == "classic" else "tf"
+        for name in ("tf", "scored"):
             vals = _all_or_none(views, name)
-            repl[name] = None if vals is None else _cat_pad(vals, rows)
+            repl[name] = None if vals is None else _cat_pad(
+                vals, rows, name == match
+            )
         return dataclasses.replace(v0, **repl)
     if isinstance(config, LexicalLshConfig):
-        repl["sig"] = _cat_pad([v.sig for v in views], rows)
-        vecs = _all_or_none(views, "vectors")
-        repl["vectors"] = None if vecs is None else _cat_pad(vecs, rows)
+        repl["sig"] = _cat_pad([v.sig for v in views], rows, True)
         return dataclasses.replace(v0, **repl)
     if isinstance(config, KdTreeConfig):
         from repro.kernels.fused_topk import ops as fused
@@ -202,23 +229,16 @@ def _packed_view(config, views: Sequence[Any], rows: int):
                 for v in views
             ],
             rows,
+            True,
         )
         repl["split_dim"] = repl["split_val"] = repl["perm"] = None
-        vecs = _all_or_none(views, "vectors")
-        repl["vectors"] = None if vecs is None else _cat_pad(vecs, rows)
         return dataclasses.replace(v0, **repl)
-    if isinstance(config, BruteForceConfig):
-        repl["pq"] = _pack_pq(views, rows)
-        vecs = _all_or_none(views, "vectors")
-        repl["vectors"] = None if vecs is None else _cat_pad(vecs, rows)
-        if repl["vectors"] is None and repl["pq"] is None:
-            raise PackedUnsupported(
-                "brute-force segments carry neither vectors nor postings"
-            )
-        return dataclasses.replace(v0, **repl)
-    raise PackedUnsupported(
-        f"no packed layout for config type {type(config).__name__}"
-    )
+    repl["pq"] = _pack_pq(views, rows)
+    if repl["vectors"] is None and repl["pq"] is None:
+        raise PackedUnsupported(
+            "brute-force segments carry neither vectors nor postings"
+        )
+    return dataclasses.replace(v0, **repl)
 
 
 def _doc_leaf_paths(config, view) -> List[Tuple[str, ...]]:
@@ -280,6 +300,7 @@ class ExecutableCache:
     def __init__(self, capacity: int = 64):
         self.capacity = capacity
         self._entries: "OrderedDict[Any, Any]" = OrderedDict()
+        self._temp: Dict[int, Optional[int]] = {}  # id(exe) -> temp bytes
         self.hits = 0
         self.compiles = 0
         self.evictions = 0
@@ -300,16 +321,26 @@ class ExecutableCache:
             self._entries.move_to_end(full_key)
             self.hits += 1
             return hit
-        with obs.span("packed.compile", kind=key[0]):
+        with obs.span("packed.compile", kind=key[0]) as sp:
             exe = jax.jit(build_fn(), donate_argnums=donate_argnums).lower(
                 *args
             ).compile()
+            mem = exe.memory_analysis()
+            temp = None if mem is None else mem.temp_size_in_bytes
+            sp.set_metadata(temp_bytes=temp)
         self.compiles += 1
         self._entries[full_key] = exe
+        self._temp[id(exe)] = temp
         while len(self._entries) > self.capacity:
-            self._entries.popitem(last=False)
+            _, old = self._entries.popitem(last=False)
+            self._temp.pop(id(old), None)
             self.evictions += 1
         return exe
+
+    def temp_bytes(self, exe) -> Optional[int]:
+        """Compiled scratch (``temp_size_in_bytes``) of a cached executable;
+        None once evicted or where the backend reports none."""
+        return self._temp.get(id(exe))
 
     def executables(self, kind: str) -> List[Any]:
         """The cached executables of one kind ("search" or "append")."""
@@ -318,6 +349,7 @@ class ExecutableCache:
 
     def clear(self) -> None:
         self._entries.clear()
+        self._temp.clear()
         self.hits = self.compiles = self.evictions = 0
 
     def stats(self) -> dict:
@@ -359,6 +391,7 @@ class PackedSegments:
     seg_names: Tuple[str, ...]
     seg_rows: Tuple[int, ...]
     appends: int = 0               # donated incremental repacks absorbed
+    search_temp_bytes: Optional[int] = None  # of the last search executable
     bm_cache: Dict[int, Any] = dataclasses.field(default_factory=dict)
 
     @property
@@ -589,14 +622,14 @@ def packed_search(
     def build():
         # Named for the trace: the executable's module is jit_packed_search.
         def packed_search(view, live, fm_in, q_rep_in, q_norm_in, bm_in):
+            from repro.core import pipeline as pl
+
             filt = None
             if use_filt:
                 filt = live if fm_in is None else (
                     fm_in & (live if fm_in.ndim == 1 else live[None, :])
                 )
             if n_keep is not None:
-                from repro.core import pipeline as pl
-
                 keep = min(n_keep, bm_in.num_blocks)
                 s, i = pl.BlockMaxMatcher(n_keep=keep)(
                     view, q_rep_in, depth, bm=bm_in,
@@ -608,15 +641,7 @@ def packed_search(
                     filt=filt, n_docs=n_docs,
                 )
             if rerank:
-                rows = view.vq.q if quantized else view.vectors
-                safe = jnp.clip(i, 0, rows.shape[0] - 1)
-                cand = rows[safe]  # (B, d, dim)
-                rs = jnp.einsum(
-                    "bd,bcd->bc", q_norm_in, cand.astype(jnp.float32)
-                )
-                if quantized:
-                    rs = rs * view.vq.scale[safe]
-                rs = jnp.where(i >= 0, rs, -jnp.inf)
+                rs = pl.candidate_scores(view, q_norm_in, i, quantized)
                 out_s, pos = jax.lax.top_k(rs, k_out)
                 return out_s, jnp.take_along_axis(i, pos, axis=-1)
             return s[:, :k_out], i[:, :k_out]
@@ -628,4 +653,5 @@ def packed_search(
         use_filt, n_docs, n_keep,
     )
     exe = cache.get(key, build, args)
+    pk.search_temp_bytes = cache.temp_bytes(exe)
     return exe(*args)

@@ -228,29 +228,29 @@ class FakeWordsMatcher:
     def operands(self, index, q_tf: jax.Array, dtype) -> Tuple[jax.Array, jax.Array]:
         """(query operand, stored matrix) for this scoring mode; ``dtype``
         is the dot-mode query dtype (int8 for the MXU kernel, int32 for the
-        XLA einsum)."""
+        XLA einsum).  The query is widened to the stored matrix, which a
+        packed view keeps lane-aligned."""
         from repro.core import fakewords
+        from repro.kernels.fused_topk import ops as fused
 
         n = self.df_num_docs if self.df_num_docs is not None else index.num_docs
         if self.scoring == "classic":
-            return (
-                fakewords.classic_query(
-                    index, q_tf, self.df_max_ratio, num_docs=n),
-                index.scored,
-            )
-        if self.signed_store:
+            qv = fakewords.classic_query(
+                index, q_tf, self.df_max_ratio, num_docs=n)
+            docs = index.scored
+        elif self.signed_store:
             # index.tf holds the SIGNED (N, m) matrix; fold the sign-split
             # keep mask down to m terms.
             keep = fakewords.df_prune_mask(index.df, n, self.df_max_ratio)
             m = index.tf.shape[1]
             keep_m = keep[:m] & keep[m:] if keep.shape[0] == 2 * m else keep[:m]
             qv = (fakewords.signed_query(q_tf) * keep_m).astype(dtype)
-            return qv, index.tf
-        return (
-            fakewords.dot_query(
-                index, q_tf, self.df_max_ratio, dtype=dtype, num_docs=n),
-            index.tf,
-        )
+            docs = index.tf
+        else:
+            qv = fakewords.dot_query(
+                index, q_tf, self.df_max_ratio, dtype=dtype, num_docs=n)
+            docs = index.tf
+        return fused.pad_query(qv, docs.shape[-1]), docs
 
     def quantized_query(self, index, q_tf: jax.Array) -> jax.Array:
         """bf16 query operand for the packed-postings path (docs/DESIGN.md
@@ -347,6 +347,7 @@ class LshMatcher:
         nd = index.num_docs if n_docs is None else n_docs
         ndk = None if nd == index.num_docs else nd
         d = min(depth, nd)
+        sig_q = fused.pad_query(sig_q, index.sig.shape[-1])
         if _use_kernel(use_kernel):
             return fused.lsh_topk(sig_q, index.sig, d, filt=filt, n_docs=ndk)
         scores = lexical_lsh.match_scores(sig_q, index.sig).astype(jnp.float32)
@@ -437,6 +438,7 @@ class CosineMatcher:
                 q_norm, index.pq.q, index.pq.scale, d,
                 index.pq.bits, index.pq.group, filt=filt, n_docs=ndk,
             )
+        q_norm = fused.pad_query(q_norm, index.vectors.shape[-1])
         if _use_kernel(use_kernel):
             return fused.cosine_topk(
                 index.vectors, q_norm, d, filt=filt, n_docs=ndk)
@@ -570,11 +572,11 @@ def candidate_scores(
     index, queries: jax.Array, cand_ids: jax.Array, quantized: bool = False
 ) -> jax.Array:
     """(B, d) cosine of each candidate against its query; id -1 = padding,
-    masked to -inf.  The ONE rerank-gather both rerankers and the
-    distributed local-rerank merge share.  ``quantized`` reads the int8
-    :class:`repro.core.types.QuantizedStore` (``index.vq``) — the gather
-    moves ~4x fewer HBM bytes and dequantizes with one per-doc multiply —
-    instead of the fp32 originals."""
+    masked to -inf.  The ONE rerank-gather both rerankers, the packed
+    search and the distributed local-rerank merge share.  ``quantized``
+    reads the int8 :class:`repro.core.types.QuantizedStore` (``index.vq``)
+    — the gather moves ~4x fewer HBM bytes and dequantizes with one
+    per-doc multiply — instead of the fp32 originals."""
     safe = jnp.maximum(cand_ids, 0)
     if quantized:
         assert index.vq is not None, (
@@ -589,7 +591,10 @@ def candidate_scores(
             "rerank requires the index to keep original vectors "
             "(build with keep_vectors=True / rerank_store='exact')"
         )
-        s = jnp.einsum("bd,bcd->bc", queries, index.vectors[safe])
+        # A packed view stores lane-aligned rows: slice the gathered
+        # candidates back to the query's width, never the corpus.
+        cand = index.vectors[safe][..., : queries.shape[-1]]
+        s = jnp.einsum("bd,bcd->bc", queries, cand)
     return jnp.where(cand_ids >= 0, s, -jnp.inf)
 
 

@@ -283,6 +283,50 @@ def _depth_pad(depth: int, merge: str) -> int:
     return common.next_pow2(dpad) if merge == "bitonic" else dpad
 
 
+def _reduce_block(t: int) -> int:
+    """Reduce-axis block the wrappers pick for a ``t``-wide operand by
+    default: 512, capped at ``t`` rounded up to the lane width.  A caller's
+    smaller ``bk`` is capped the same way."""
+    return min(512, common.round_up(t, common.LANE))
+
+
+def aligned_width(t: int) -> int:
+    """Reduce-axis width the wrappers stream a ``t``-wide operand at: ``t``
+    rounded up to its reduce block.  A per-doc leaf stored at this width
+    (filled by :func:`lane_align`) reaches the kernel with no per-call pad,
+    and XLA keeps it row-major, so no call relays the corpus out."""
+    return common.round_up(t, _reduce_block(t))
+
+
+def _fillers(dtype) -> tuple:
+    """(query filler, doc filler) of the reduce-axis pad: distinct sentinels
+    for uint32 LSH signatures, so padding never collides; zeros for the
+    GEMM dtypes, which add nothing to a dot product."""
+    if dtype == jnp.uint32:
+        return LSH_SENTINEL, np.uint32(LSH_SENTINEL - 1)
+    return 0, 0
+
+
+def lane_align(docs: jax.Array, rows: int | None = None) -> jax.Array:
+    """Pad a per-doc ``(N, t)`` leaf to ``(rows, aligned_width(t))`` in one
+    pass: zero rows, and the columns filled as the wrappers' per-call pad
+    would fill them."""
+    n, t = docs.shape
+    pad = ((0, (rows or n) - n), (0, aligned_width(t) - t))
+    if pad == ((0, 0), (0, 0)):
+        return docs
+    fill = ((0, 0), (0, _fillers(docs.dtype)[1]))
+    return jnp.pad(docs, pad, constant_values=fill)
+
+
+def pad_query(q: jax.Array, width: int) -> jax.Array:
+    """Pad a query operand's reduce axis to a stored leaf's ``width`` with
+    the query filler, so XLA reference scoring reads a lane-aligned leaf
+    as it is.  Exact: GEMM pads contribute zero products and the LSH query
+    sentinel never counts."""
+    return common.pad_dim(q, q.ndim - 1, width, value=_fillers(q.dtype)[0])
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -325,9 +369,9 @@ def fused_topk(
     if mode == "lsh":
         # The compare stage materializes a (bq, bn, bk) equality tensor in
         # VMEM — size tiles like ``lsh_match`` (~4 MB), not like the GEMM.
-        bq, bn, bk = bq or 16, bn or 128, bk or 512
+        bq, bn = bq or 16, bn or 128
     else:
-        bq, bn, bk = bq or 128, bn or 512, bk or 512
+        bq, bn = bq or 128, bn or 512
     b, t = q.shape
     n = docs.shape[0]
     if n_docs is None:
@@ -336,18 +380,19 @@ def fused_topk(
     assert depth <= n_docs, f"depth {depth} > corpus size {n_docs}"
     bq = min(bq, common.round_up(b, 8))
     bn = min(bn, common.round_up(n, common.LANE))
-    bk = min(bk, common.round_up(t, common.LANE))
+    bk = min(bk or 512, _reduce_block(t))
     if mode == "lsh":
         # Distinct fillers so padding never matches (query pad is masked).
-        qp = common.pad_dim(common.pad_dim(q, 0, bq), 1, bk, value=LSH_SENTINEL)
-        dp = common.pad_dim(
-            common.pad_dim(docs, 0, bn), 1, bk, value=np.uint32(LSH_SENTINEL - 1)
-        )
+        q_fill, d_fill = _fillers(jnp.uint32)
+        qp = common.pad_dim(common.pad_dim(q, 0, bq), 1, bk, value=q_fill)
+        dp = common.pad_dim(common.pad_dim(docs, 0, bn), 1, bk, value=d_fill)
         acc_dtype = jnp.int32
     else:
         qp = common.pad_dim(common.pad_dim(q, 0, bq), 1, bk)
         dp = common.pad_dim(common.pad_dim(docs, 0, bn), 1, bk)
         acc_dtype = jnp.int32 if q.dtype in _INT_DTYPES else jnp.float32
+    # A lane-aligned stored leaf (``lane_align``) passes through untouched.
+    assert dp.shape[1] == qp.shape[1], (dp.shape, qp.shape)
     dpad = _depth_pad(depth, merge)
     grid = (qp.shape[0] // bq, dp.shape[0] // bn, qp.shape[1] // bk)
     operands = [qp, dp]
@@ -487,17 +532,17 @@ def fused_topk_gathered(
         row_ids = jnp.where(filt != 0, row_ids.astype(jnp.int32), BIG_ID)
     assert depth <= r, f"depth {depth} > candidate count {r}"
     bn = min(bn, common.round_up(r, common.LANE))
-    bk = min(bk, common.round_up(t, common.LANE))
+    bk = min(bk, _reduce_block(t))
     if mode == "lsh":
-        qp = common.pad_dim(q, 1, bk, value=LSH_SENTINEL)
-        dp = common.pad_dim(
-            common.pad_dim(docs, 1, bn), 2, bk, value=np.uint32(LSH_SENTINEL - 1)
-        )
+        q_fill, d_fill = _fillers(jnp.uint32)
+        qp = common.pad_dim(q, 1, bk, value=q_fill)
+        dp = common.pad_dim(common.pad_dim(docs, 1, bn), 2, bk, value=d_fill)
         acc_dtype = jnp.int32
     else:
         qp = common.pad_dim(q, 1, bk)
         dp = common.pad_dim(common.pad_dim(docs, 1, bn), 2, bk)
         acc_dtype = jnp.int32 if q.dtype in _INT_DTYPES else jnp.float32
+    assert dp.shape[2] == qp.shape[1], (dp.shape, qp.shape)
     # Padding rows get an out-of-range id so the in-kernel mask drops them.
     rp = common.pad_dim(row_ids.astype(jnp.int32), 1, bn, value=BIG_ID)
     dpad = _depth_pad(depth, merge)

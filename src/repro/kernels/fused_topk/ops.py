@@ -20,6 +20,8 @@ from repro.kernels.fused_topk.kernel import (
     fused_topk_gathered,
     fused_topk_gathered_quantized,
     fused_topk_quantized,
+    lane_align,
+    pad_query,
 )
 
 __all__ = [
@@ -36,6 +38,8 @@ __all__ = [
     "fused_topk_gathered_quantized",
     "postings_topk",
     "postings_topk_gathered",
+    "lane_align",
+    "pad_query",
 ]
 
 

@@ -647,6 +647,9 @@ class AnnService:
         pk = getattr(self.ann, "_packed", None)
         if pk is not None:
             out["packed_bucket"] = pk.bucket
+            # Compiled scratch of the search executable last run on this
+            # snapshot: about 0 unless a call relays the corpus out.
+            out["packed_search_temp_bytes"] = pk.search_temp_bytes
             out["packed_rows"] = pk.n_rows
             out["packed_live"] = pk.n_live
             out["packed_occupancy"] = round(pk.n_rows / pk.bucket, 4)

@@ -4,11 +4,14 @@ results — ids equal, scores allclose — across segment counts, encodings,
 and filters, while the shape-bucketed executable cache keeps recompiles
 bounded across refresh cycles.
 """
+import dataclasses
+import glob
 import os
 import subprocess
 import sys
 import textwrap
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,28 +19,85 @@ import pytest
 from repro.core import bruteforce
 from repro.core import packed as packed_mod
 from repro.core.segments import IndexWriter
-from repro.core.types import FakeWordsConfig, KdTreeConfig, LexicalLshConfig
+from repro.core.types import (
+    BruteForceConfig,
+    FakeWordsConfig,
+    KdTreeConfig,
+    LexicalLshConfig,
+)
 from tools.reprolint.trace_audit import assert_max_traces
 
-# The encodings the ISSUE's parity matrix names: classic fp32 postings,
-# dot-mode int8 postings, int4 quantized-classic postings, LSH signatures.
+CLASSIC = FakeWordsConfig(quantization=50)
+DOT = FakeWordsConfig(quantization=50, scoring="dot")
+LSH = LexicalLshConfig(buckets=64, hashes=2)
+LSH_300 = LexicalLshConfig(buckets=150, hashes=2)
+
+# The parity matrix: classic fp32 postings, dot-mode int8 postings, int4
+# quantized-classic postings, LSH signatures at 32-d; then the served
+# widths, which the packed view stores lane-aligned (classic T = 400 ->
+# 512, LSH S = 300 -> 384, 200-d vectors -> 256), on the XLA path and on
+# the kernel (interpret mode off a TPU).
 MATRIX = [
-    ("classic", FakeWordsConfig(quantization=50), "fp32", "exact"),
-    ("dot-int8", FakeWordsConfig(quantization=50, scoring="dot"), "int8", "int8"),
-    ("int4", FakeWordsConfig(quantization=50), "int4", "exact"),
-    ("lsh", LexicalLshConfig(buckets=64, hashes=2), "fp32", "exact"),
+    ("classic", CLASSIC, "fp32", "exact", 32, False),
+    ("dot-int8", DOT, "int8", "int8", 32, False),
+    ("int4", CLASSIC, "int4", "exact", 32, False),
+    ("lsh", LSH, "fp32", "exact", 32, False),
+    ("classic-t400", CLASSIC, "fp32", "exact", 200, False),
+    ("classic-t400-kernel", CLASSIC, "fp32", "exact", 200, True),
+    ("lsh-s300", LSH_300, "fp32", "exact", 200, False),
+    ("lsh-s300-kernel", LSH_300, "fp32", "exact", 200, True),
+    ("bruteforce-d200", BruteForceConfig(), "fp32", "exact", 200, False),
+    ("bruteforce-d200-kernel", BruteForceConfig(), "fp32", "exact", 200, True),
 ]
 
 
-def _writer(cfg, postings, store, n_segments, rng, dim=32, seg_docs=40):
+def _writer(cfg, postings, store, n_segments, rng, dim=32, seg_docs=40,
+            use_kernel=False):
     w = IndexWriter(
         cfg, rerank_store=store, primary_postings=postings,
-        merge_policy=None, use_kernel=False,
+        merge_policy=None, use_kernel=use_kernel,
     )
     for _ in range(n_segments):
         w.add(rng.normal(size=(seg_docs, dim)).astype(np.float32))
         w.flush()
     return w
+
+
+def _unaligned(reader):
+    """The reader's packed snapshot with every lane-aligned leaf cut back
+    to its stat view's (logical) width: the reference layout."""
+    pk = reader.packed_segments()
+    v0 = reader._ensure_views()[0][0]
+    cut = {}
+    for path in packed_mod._doc_leaf_paths(reader.config, pk.view):
+        leaf = packed_mod._get_path(pk.view, path)
+        ref = packed_mod._get_path(v0, path)
+        # A stat view may leave the kd lift [d; -|d|^2] to the pack.
+        width = v0.reduced.shape[1] + 1 if ref is None else ref.shape[-1]
+        if leaf.ndim == 2 and leaf.shape[1] != width:
+            cut[path] = leaf[:, :width]
+    assert cut, "nothing is stored lane-aligned"
+    view = packed_mod._replace_paths(pk.view, cut)
+    return dataclasses.replace(pk, view=view, bm_cache={})
+
+
+def _assert_layout_bitwise(reader, queries, fm=None, k=10, depth=50,
+                           rerank=False, n_keep=None, block=64):
+    """The lane-aligned packed view answers bitwise like the same snapshot
+    at its logical widths: ids and scores, kernel or XLA path."""
+    q = bruteforce.l2_normalize(queries)
+    out = []
+    for pk in (reader.packed_segments(), _unaligned(reader)):
+        bm = None if n_keep is None else packed_mod.packed_blockmax(
+            pk, reader.config, block)
+        out.append(packed_mod.packed_search(
+            pk, reader.pipeline, reader._packed_matcher(), q, k, depth,
+            rerank, reader.quantized_rerank, reader.use_kernel, fm=fm,
+            n_keep=n_keep, bm=bm,
+        ))
+    (s0, i0), (s1, i1) = out
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
 
 
 def _assert_packed_equals_loop(reader, queries, fm=None, k=10, depth=50):
@@ -54,25 +114,38 @@ def _assert_packed_equals_loop(reader, queries, fm=None, k=10, depth=50):
         np.testing.assert_allclose(
             np.asarray(s0), np.asarray(s1), rtol=1e-5, atol=1e-6
         )
+        _assert_layout_bitwise(reader, queries, fm, k, depth, rerank)
+
+
+def _delete_tenth(w, reader, rng):
+    """Delete 10% of the docs and return the new reader."""
+    n = reader.max_doc
+    w.delete(rng.choice(n, size=max(1, n // 10), replace=False))
+    return w.refresh()
 
 
 @pytest.mark.parametrize("n_segments", [1, 4, 16])
 @pytest.mark.parametrize(
-    "name,cfg,postings,store", MATRIX, ids=[m[0] for m in MATRIX]
+    "name,cfg,postings,store,dim,use_kernel", MATRIX,
+    ids=[m[0] for m in MATRIX],
 )
-def test_packed_parity(name, cfg, postings, store, n_segments, rng):
+def test_packed_parity(
+    name, cfg, postings, store, dim, use_kernel, n_segments, rng
+):
     """Packed single-launch == per-segment loop: exact ids, allclose
-    scores, rerank on and off — unfiltered AND under deletes ∧ predicate."""
-    w = _writer(cfg, postings, store, n_segments, rng)
+    scores, rerank on and off — unfiltered AND under deletes ∧ predicate,
+    over a bucket-padded tail; and bitwise what the same snapshot answers
+    at its logical widths."""
+    w = _writer(cfg, postings, store, n_segments, rng, dim=dim,
+                use_kernel=use_kernel)
     reader = w.refresh()
-    queries = jnp.asarray(rng.normal(size=(6, 32)).astype(np.float32))
+    assert reader.packed_segments().n_rows < reader.packed_segments().bucket
+    queries = jnp.asarray(rng.normal(size=(6, dim)).astype(np.float32))
     _assert_packed_equals_loop(reader, queries)
 
     # Deletes ∧ predicate: drop 10% of docs, keep a random 70% predicate.
-    n = reader.max_doc
-    w.delete(rng.choice(n, size=max(1, n // 10), replace=False))
-    reader = w.refresh()
-    fm = jnp.asarray(rng.random(n) < 0.7)
+    reader = _delete_tenth(w, reader, rng)
+    fm = jnp.asarray(rng.random(reader.max_doc) < 0.7)
     _assert_packed_equals_loop(reader, queries, fm=fm)
 
 
@@ -85,14 +158,20 @@ def test_packed_parity_per_query_filter(rng):
     _assert_packed_equals_loop(reader, queries, fm=fm)
 
 
-def test_packed_kdtree_scan_parity(rng):
-    """The kd-scan encoding (global-stats refit) packs and matches too."""
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["xla", "kernel"])
+def test_packed_kdtree_scan_parity(use_kernel, rng):
+    """The kd-scan encoding (global-stats refit) packs and matches too: the
+    9-wide lift is stored 128 wide, over a padded tail and deletes."""
     w = _writer(
-        KdTreeConfig(dims=8, backend="scan"), "fp32", "exact", 4, rng
+        KdTreeConfig(dims=8, backend="scan"), "fp32", "exact", 4, rng,
+        use_kernel=use_kernel,
     )
     reader = w.refresh()
+    pk = reader.packed_segments()
+    assert pk.view.lifted.shape == (pk.bucket, 128) and pk.n_rows < pk.bucket
     queries = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
     _assert_packed_equals_loop(reader, queries)
+    _assert_packed_equals_loop(_delete_tenth(w, reader, rng), queries)
 
 
 def test_bucket_ladder():
@@ -169,21 +248,30 @@ def test_append_rung_narrowing(rng):
     assert pk.appends == 4
 
 
-def test_donated_incremental_append(rng):
+@pytest.mark.parametrize(
+    "cfg,dim,use_kernel",
+    [(LSH, 32, False), (LSH_300, 200, False), (LSH_300, 200, True)],
+    ids=["s128-xla", "s300-xla", "s300-kernel"],
+)
+def test_donated_incremental_append(cfg, dim, use_kernel, rng):
     """Append-only refreshes of a stats-static encoding absorb the prior
-    snapshot's packed buffers in place instead of re-concatenating."""
-    cfg = LexicalLshConfig(buckets=64, hashes=2)
+    snapshot's packed buffers in place instead of re-concatenating; the
+    appended block is stored lane-aligned like the rest, and deletes in the
+    old rows ride along."""
     # 600 docs -> bucket 768, and 620 stays in the same rung with room
     # for the 128-row append block.
-    w = _writer(cfg, "fp32", "exact", 1, rng, seg_docs=600)
+    w = _writer(cfg, "fp32", "exact", 1, rng, dim=dim, seg_docs=600,
+                use_kernel=use_kernel)
     r0 = w.refresh()
     assert r0.packed_segments().appends == 0
-    w.add(rng.normal(size=(20, 32)).astype(np.float32))
+    w.delete(rng.choice(600, size=60, replace=False))
+    w.add(rng.normal(size=(20, dim)).astype(np.float32))
     w.flush()
     r1 = w.refresh()
     pk = r1.packed_segments()
     assert pk.appends == 1  # donated dynamic_update_slice, not a repack
-    queries = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
+    assert pk.view.sig.shape[1] % 128 == 0
+    queries = jnp.asarray(rng.normal(size=(4, dim)).astype(np.float32))
     _assert_packed_equals_loop(r1, queries)
     # The donation neutered the old reader's pack; it lazily repacks.
     assert r0._packed is None
@@ -239,16 +327,27 @@ def test_packed_false_forces_loop_and_env_kill_switch(rng, monkeypatch):
     assert reader._packed is not None
 
 
-def test_packed_blockmax_exact_at_full_keep(rng):
+@pytest.mark.parametrize(
+    "cfg,dim,use_kernel",
+    [
+        (CLASSIC, 32, False), (LSH, 32, False),
+        (CLASSIC, 200, False), (CLASSIC, 200, True),
+        (LSH_300, 200, False), (LSH_300, 200, True),
+        (DOT, 32, False), (DOT, 200, True),
+    ],
+    ids=["classic-t64-xla", "lsh-s128-xla", "classic-t400-xla",
+         "classic-t400-kernel", "lsh-s300-xla", "lsh-s300-kernel",
+         "dot-t64-xla", "dot-t400-kernel"],
+)
+def test_packed_blockmax_exact_at_full_keep(cfg, dim, use_kernel, rng):
     """blockmax_keep = every block is a pure reshuffle of the exact scan:
-    segmented blockmax (over the packed view) == the unpruned loop."""
-    for cfg in (
-        FakeWordsConfig(quantization=50),
-        LexicalLshConfig(buckets=64, hashes=2),
-    ):
-        w = _writer(cfg, "fp32", "exact", 4, rng, seg_docs=40)
-        reader = w.refresh()
-        queries = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
+    segmented blockmax (over the lane-aligned packed view) == the unpruned
+    loop, before and after deletes."""
+    w = _writer(cfg, "fp32", "exact", 4, rng, dim=dim, seg_docs=40,
+                use_kernel=use_kernel)
+    reader = w.refresh()
+    queries = jnp.asarray(rng.normal(size=(4, dim)).astype(np.float32))
+    for reader in (reader, _delete_tenth(w, reader, rng)):
         s0, i0 = reader.search(queries, k=10, depth=50, packed=False)
         pk = reader.packed_segments()
         keep = pk.bucket // 64  # block_size=64 -> keep ALL blocks
@@ -260,6 +359,10 @@ def test_packed_blockmax_exact_at_full_keep(rng):
         np.testing.assert_allclose(
             np.asarray(s0), np.asarray(s1), rtol=1e-5, atol=1e-6
         )
+        _assert_layout_bitwise(reader, queries, n_keep=keep)
+        # Keeping 3 of 16-row blocks: the bounds over the aligned view keep
+        # the blocks the logical-width bounds keep.
+        _assert_layout_bitwise(reader, queries, n_keep=3, block=16)
 
 
 def test_packed_static_rows_bound(rng):
@@ -348,3 +451,36 @@ def test_packed_sharded_composition(rng):
         timeout=900, env=dict(os.environ, PYTHONPATH=src),
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
+
+
+def test_search_temp_bytes_on_compile_span_and_in_stats(rng, tmp_path):
+    """Each compile records the executable's temp bytes on its
+    ``packed.compile`` span; ``AnnService.stats()`` reports the search
+    executable's beside ``packed_bucket``."""
+    from repro.serve.ann_service import AnnService, AnnServiceConfig
+
+    w = _writer(LSH_300, "fp32", "exact", 1, rng, dim=200, seg_docs=200)
+    svc = AnnService(writer=w, service=AnnServiceConfig(
+        k=10, depth=50, rerank=True, max_batch=8))
+    assert svc.stats()["packed_bucket"] is None  # nothing packed yet
+    packed_mod.EXEC_CACHE.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.search_batch(rng.normal(size=(8, 200)).astype(np.float32))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    spans = [
+        dict(ev.stats)
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines for ev in line.events
+        if ev.name == "packed.compile"
+    ]
+    (search,) = [sp for sp in spans if sp["kind"] == "search"]
+    (exe,) = packed_mod.EXEC_CACHE.executables("search")
+    temp = exe.memory_analysis().temp_size_in_bytes
+    assert search["temp_bytes"] == temp
+    stats = svc.stats()
+    assert stats["packed_bucket"] == 256
+    assert stats["packed_search_temp_bytes"] == temp
