@@ -21,9 +21,9 @@ stage compiled by Mosaic.  The run then checks:
     nothing new;
   * the search executable holds a Mosaic kernel (``tpu_custom_call``).
 
-``--four-chips`` builds the same corpus doc-sharded with
-``AnnIndex.build(mesh=...)``, serves it with ``AnnService(mesh=...)``, and
-compares with a one-chip search of the same index in the same process.
+``--four-chips`` ingests the same corpus through ``IndexWriter(shards=4)``
+(doc-sharded over four chips), serves it through ``AnnService``, and
+requires the ids of a one-chip writer of the same rows on every slot.
 
 Timings printed on the way are those of one smoke run, not benchmark
 numbers.  Every failed check exits non-zero.  The last line of stdout is
@@ -47,10 +47,9 @@ import numpy as np  # noqa: E402
 
 from repro import compile_cache  # noqa: E402
 from repro.configs import ann_glove  # noqa: E402
-from repro.core import bruteforce, distributed  # noqa: E402
+from repro.core import bruteforce  # noqa: E402
 from repro.core import eval as ev  # noqa: E402
 from repro.core import packed as packed_mod  # noqa: E402
-from repro.core.index import AnnIndex  # noqa: E402
 from repro.core.segments import IndexWriter  # noqa: E402
 from repro.data import embeddings  # noqa: E402
 from repro.kernels import common  # noqa: E402
@@ -226,51 +225,43 @@ def four_chips(
     n_docs: int = N_DOCS, n_queries: int = N_QUERIES, batch: int = BATCH,
     n_chips: int = 4, kernel: bool | None = None,
 ) -> dict:
-    """Doc-sharded build + AnnService(mesh=...) vs one-device search of the
-    same index.  Per-shard depth-100 candidates are a superset of the
-    one-device depth-100 candidates, so ids are compared on the match
-    stage (rerank off), where both must return the exact global top-k of
-    the same scores; with rerank, recall against exact search is compared."""
-    mesh = distributed.make_mesh((n_chips,), ("data",))
+    """The corpus doc-sharded by ``IndexWriter(shards=n_chips)`` and served
+    by ``AnnService``, against a one-chip writer of the same rows served the
+    same way.  The sharded merge takes the global top-depth by match score
+    before the rerank, so the ids must agree on every slot, with and
+    without the rerank.  The sharded writer is freed before the one-chip
+    one is built, so chip 0 never holds both."""
     t0 = time.perf_counter()
     corpus, queries = corpus_and_queries(n_docs, n_queries)
     truth = exact_truth(corpus, queries, K, batch)
     log(f"corpus {corpus.shape} + exact truth in {time.perf_counter() - t0:.2f}s")
-
-    t0 = time.perf_counter()
-    rows = jax.device_put(
-        corpus, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data", None))
-    )
     cfg = ann_glove.make_model(CELL)
-    ann = AnnIndex.build(rows, cfg, rerank_store="exact", mesh=mesh,
-                         shard_axes=("data",))
-    jax.block_until_ready(ann.index)
-    log(f"sharded build over {n_chips} devices in {time.perf_counter() - t0:.2f}s"
-        f"{hbm()}")
-    one = AnnIndex(config=cfg, index=jax.device_put(ann.index, jax.devices()[0]))
-
+    ids = {}
+    for shards in (n_chips, 1):
+        t0 = time.perf_counter()
+        writer = IndexWriter(cfg, rerank_store="exact", shards=shards)
+        writer.add(corpus)
+        writer.flush()
+        log(f"IndexWriter(shards={shards}) ingest in {time.perf_counter() - t0:.2f}s{hbm()}")
+        for rerank in (False, True):
+            scfg = AnnServiceConfig(k=K, depth=DEPTH, rerank=rerank,
+                                    max_batch=batch, use_kernel=kernel)
+            svc = AnnService(writer=writer, service=scfg)
+            first_s, p50 = timed_first_and_steady(svc, queries, batch)
+            ids[shards, rerank] = serve_all(svc, queries, batch)
+            log(f"shards={shards} rerank={rerank}: first batch {first_s:.2f}s "
+                f"(compile included), steady p50 {p50} ms/batch (smoke timing)")
+            del svc
+        del writer
     out = {}
     for rerank in (False, True):
-        scfg = AnnServiceConfig(k=K, depth=DEPTH, rerank=rerank,
-                                max_batch=batch, use_kernel=kernel)
-        sharded = AnnService(ann, scfg, mesh=mesh, shard_axes=("data",))
-        single = AnnService(one, scfg)
-        first_s, p50 = timed_first_and_steady(sharded, queries, batch)
-        ids_s, ids_1 = serve_all(sharded, queries, batch), serve_all(single, queries, batch)
-        agree = float(np.mean(ids_s == ids_1))
-        rec_s, rec_1 = recall(truth, ids_s), recall(truth, ids_1)
+        agree = float(np.mean(ids[n_chips, rerank] == ids[1, rerank]))
+        rec_s, rec_1 = recall(truth, ids[n_chips, rerank]), recall(truth, ids[1, rerank])
         tag = "rerank" if rerank else "match only"
-        log(f"{tag}: sharded first batch {first_s:.2f}s (compile included), "
-            f"steady p50 {p50} ms/batch (smoke timing); id agreement "
-            f"sharded vs one device {agree:.4f}; recall@{K} sharded "
-            f"{rec_s:.4f}, one device {rec_1:.4f}{hbm()}")
-        out[tag] = {"id_agreement": agree, "recall_sharded": rec_s,
-                    "recall_one": rec_1, "first_batch_s": first_s, "p50_ms": p50}
-    check(out["match only"]["id_agreement"] >= MIN_ID_AGREEMENT,
-          f"sharded match ids agree on {out['match only']['id_agreement']} "
-          f"< {MIN_ID_AGREEMENT} of slots")
-    check(out["rerank"]["recall_sharded"] >= out["rerank"]["recall_one"] - MAX_RECALL_GAP,
-          "sharded recall below the one-device recall")
+        log(f"{tag}: id agreement {n_chips} chips vs one {agree:.4f}; recall@{K} "
+            f"{n_chips} chips {rec_s:.4f}, one {rec_1:.4f}")
+        out[tag] = {"id_agreement": agree, "recall_sharded": rec_s, "recall_one": rec_1}
+        check(agree == 1.0, f"{tag}: sharded ids agree on {agree} of slots, not all")
     return out
 
 

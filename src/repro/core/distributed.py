@@ -8,11 +8,20 @@ We reproduce that architecture with ``shard_map`` over the full device mesh:
      is sharded over the flattened mesh axes on the document dimension;
   2. each shard scores locally (one GEMM over its slice) and takes a local
      top-d;
-  3. *local exact rerank*: each shard recomputes exact cosine for its own
-     candidates from its local original vectors - this keeps the rerank
-     gather local (no cross-shard vector movement);
-  4. one all-gather of (score, global_id) pairs - d*(4+4) bytes per shard,
-     negligible next to the index scan - and a replicated global top-k.
+  3. *global top-depth merge*: one all-gather of (score, global_id) pairs -
+     d*(4+4) bytes per shard, negligible next to the index scan - and the
+     global top-d by match score, ties to the lower global id, exactly the
+     candidates a one-device match over the whole corpus selects;
+  4. *owned exact rerank*: each shard recomputes exact cosine for the global
+     candidates it holds from its local original vectors (no cross-shard
+     vector movement), and one max over shards of the (B, d) rerank scores
+     assembles them in the global candidate order for a replicated top-k.
+
+So a sharded search returns what one device returns over the same rows: the
+global top-``depth`` by match score, then the exact rerank
+(:func:`merge_global_depth`).  Each shard keeping and reranking its own
+top-``depth`` instead would rerank ``shards * depth`` candidates: a
+different, larger candidate set.
 
 The per-shard match phase runs the SAME stage objects as single-device
 search (:mod:`repro.core.pipeline`): ``make_sharded_search`` builds the
@@ -34,6 +43,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import pca
@@ -311,6 +321,56 @@ def build_fakewords_sharded(
 # --------------------------------------------------------------------------
 
 
+def merge_global_depth(
+    index,
+    loc_s: jax.Array,
+    loc_i: jax.Array,
+    gid: jax.Array,
+    queries: jax.Array,
+    axes: Sequence[str],
+    k: int,
+    depth: int,
+    rerank: bool,
+    quantized: bool = False,
+):
+    """Per-shard body of the one sharded merge, under ``shard_map``.
+
+    ``loc_s``/``loc_i`` are this shard's local match top list ((B, d) scores
+    and local rows, -1 = none) and ``gid`` the global doc ids of those rows.
+    One all-gather of (score, id) gives every shard the same global
+    top-``depth`` by match score, ties to the lower global id as on one
+    device.  With ``rerank`` each shard scores the candidates it owns from
+    its local store (-inf elsewhere), and a max over shards of that (B,
+    depth) array gives the exact scores in the global candidate order; the
+    top-``k`` of them is replicated on every shard."""
+    axes = tuple(axes)
+    d_loc = loc_s.shape[1]
+    all_s = jax.lax.all_gather(loc_s, axes, axis=1, tiled=True)
+    all_g = jax.lax.all_gather(gid, axes, axis=1, tiled=True)
+    pos = jax.lax.broadcasted_iota(jnp.int32, all_s.shape, 1)
+    d = min(depth, all_s.shape[1])
+    neg, top_g, top_p = jax.lax.sort((-all_s, all_g, pos), dimension=1, num_keys=2)
+    top_s, top_g, top_p = -neg[:, :d], top_g[:, :d], top_p[:, :d]
+    k = min(k, d)
+    if not rerank:
+        return top_s[:, :k], top_g[:, :k]
+    own = (top_p // d_loc == flat_axis_index(axes)) & (top_g >= 0)
+    rows = jnp.take_along_axis(loc_i, top_p % d_loc, axis=1)
+    rs = pl.candidate_scores(index, queries, jnp.where(own, rows, -1), quantized)
+    rs = jax.lax.pmax(rs, axes)
+    out_s, pos2 = jax.lax.top_k(rs, k)
+    return out_s, jnp.take_along_axis(top_g, pos2, axis=1)
+
+
+def merge_bytes(batch: int, depth: int, shards: int, rerank: bool) -> int:
+    """Bytes each chip receives from the merge's collectives in one launch:
+    the all-gathered (score, id) pairs of every shard, plus the (B, depth)
+    rerank scores reduced over shards.  0 on one device."""
+    if shards <= 1:
+        return 0
+    return batch * depth * (8 * shards + (4 if rerank else 0))
+
+
 def make_sharded_search(
     mesh: Mesh,
     config,
@@ -382,26 +442,15 @@ def make_sharded_search(
     kernel_local = fused.resolve_use_kernel(use_kernel)
     matcher = pl.make_matcher(config, score_tile=score_tile, tile_unroll=tile_unroll)
 
+    quantized = rerank_store == "int8"
+
     def merge_global(index, loc_s, loc_i, queries):
-        shard = flat_axis_index(axes)
-        n_local = index.num_docs
-        valid = loc_i >= 0
-        if rerank:
-            # Exact rerank against the *local* store — fp32 originals or the
-            # int8 quantized store — so there is no cross-shard vector
-            # movement.  -1 padding slots would otherwise gather doc 0 and
-            # earn a real cosine score; candidate_scores masks them to -inf.
-            loc_s = pl.candidate_scores(
-                index, queries, loc_i, quantized=rerank_store == "int8"
-            )
-        # Invalid slots keep id -1 (never ``-1 + shard * n_local``).
-        glob_i = jnp.where(valid, loc_i + shard * n_local, -1)
-        # Tiny collective: d*(score,id) per shard.
-        all_s = jax.lax.all_gather(loc_s, axes, axis=1, tiled=True)
-        all_i = jax.lax.all_gather(glob_i, axes, axis=1, tiled=True)
-        top_s, pos = jax.lax.top_k(all_s, k)
-        top_i = jnp.take_along_axis(all_i, pos, axis=-1)
-        return top_s, top_i
+        # Global ids of a contiguous doc-sharded corpus: local row + this
+        # shard's offset; invalid slots keep id -1.
+        gid = jnp.where(loc_i >= 0, loc_i + flat_axis_index(axes) * index.num_docs, -1)
+        return merge_global_depth(
+            index, loc_s, loc_i, gid, queries, axes, k, depth, rerank, quantized
+        )
 
     def local_search(index, q_rep, queries, filt=None):
         loc_s, loc_i = matcher(
@@ -520,78 +569,22 @@ def shard_index(mesh: Mesh, index, axes: Sequence[str]):
 
 
 # --------------------------------------------------------------------------
-# Packed segmented search over a pod (docs/DESIGN.md §14)
+# The doc-sharded writer's mesh (docs/DESIGN.md §5); its per-chip packed
+# views and their search live in core/packed.py
 # --------------------------------------------------------------------------
 
+#: Mesh axis of a doc-sharded ``IndexWriter(shards=N)``.
+SHARD_AXES = ("shard",)
 
-def make_packed_segmented_search(
-    mesh: Mesh,
-    reader,
-    axes: Sequence[str],
-    k: int = 10,
-    depth: int = 100,
-    rerank: bool = False,
-    filter_mask=None,
-    score_tile: int = 262_144,
-    use_kernel: Optional[bool] = None,
-):
-    """Compose the packed single-launch segmented path with the pod
-    fan-out: pack a :class:`repro.core.segments.SegmentedAnnIndex`
-    snapshot into its superbuffer (``core/packed.py``), doc-shard the
-    packed leaves over ``axes``, and serve through
-    :func:`make_sharded_search`'s filtered path with the composed
-    liveDocs ∧ row-validity [∧ predicate] bitmap sharded WITH the rows.
 
-    The packed layout concatenates segments in global-id order, so packed
-    row g IS global doc id g — and ``make_sharded_search`` emits
-    ``local row + shard_offset``, so the pod returns the reader's global
-    doc ids with no remap.  ``filter_mask`` is the same (max_doc,)
-    global-id predicate bitmap ``SegmentedAnnIndex.search`` takes.
-
-    Returns ``(search_fn, sharded_index, sharded_filt)``; call as
-    ``search_fn(sharded_index, q_rep, queries, sharded_filt)`` with
-    ``q_rep = reader.encode_queries(queries)``.
-    """
-    from repro.core import packed as packed_mod
-
-    axes = tuple(axes)
-    pk = reader.packed_segments()
-    if pk is None:
+def device_mesh(shards: int) -> Mesh:
+    """A 1-D Auto-axis mesh over the first ``shards`` local devices."""
+    devices = jax.local_devices()
+    if len(devices) < shards:
         raise ValueError(
-            "packed single-launch path unavailable for this snapshot: "
-            f"{reader._packed_err}"
+            f"shards={shards} needs {shards} local devices; found {len(devices)}"
         )
-    n_shards = flat_axis_size(mesh, axes)
-    if pk.bucket % n_shards:
-        raise ValueError(
-            f"packed bucket {pk.bucket} rows not divisible by {n_shards} "
-            "shards; choose a mesh whose flattened size divides the "
-            "bucket ladder rung"
-        )
-    view = pk.view
-    if reader.quantized_rerank:
-        rerank_store = "int8"
-    elif getattr(view, "vectors", None) is not None:
-        rerank_store = "exact"
-    else:
-        rerank_store = "none"
-    pq = getattr(view, "pq", None)
-    search_fn = make_sharded_search(
-        mesh, reader.config, axes, k=k, depth=depth, rerank=rerank,
-        score_tile=score_tile, use_kernel=use_kernel,
-        rerank_store=rerank_store,
-        postings_bits=0 if pq is None else pq.bits,
-        filtered=True,
+    return Mesh(
+        np.array(devices[:shards]), SHARD_AXES,
+        axis_types=(jax.sharding.AxisType.Auto,),
     )
-    filt = pk.live
-    if filter_mask is not None:
-        fm = jnp.asarray(filter_mask)
-        if fm.ndim != 1 or fm.shape[0] != reader.max_doc:
-            raise ValueError(
-                "pod-sharded filtering takes a (max_doc,) per-doc bitmap "
-                f"(got shape {fm.shape}, max_doc={reader.max_doc})"
-            )
-        filt = filt & packed_mod._pad_mask_cols(fm, pk.bucket)
-    sharded_index = shard_index(mesh, view, axes)
-    sharded_filt = jax.device_put(filt, NamedSharding(mesh, P(axes)))
-    return search_fn, sharded_index, sharded_filt

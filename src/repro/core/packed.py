@@ -59,8 +59,10 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
+from repro.core import distributed
 from repro.core.types import (
     BruteForceConfig,
     FakeWordsConfig,
@@ -77,6 +79,7 @@ __all__ = [
     "EXEC_CACHE",
     "bucket_rows",
     "pack_segments",
+    "pack_segments_sharded",
     "packed_search",
     "packed_blockmax",
 ]
@@ -393,6 +396,18 @@ class PackedSegments:
     appends: int = 0               # donated incremental repacks absorbed
     search_temp_bytes: Optional[int] = None  # of the last search executable
     bm_cache: Dict[int, Any] = dataclasses.field(default_factory=dict)
+    # Doc-sharded snapshots (pack_segments_sharded): ``bucket`` is each
+    # chip's, ``view`` and ``live`` hold ``shards * bucket`` rows sharded
+    # over ``mesh``, ``ids`` maps each chip's local row to its global doc
+    # id (-1 = pad), and ``shard_live`` counts each chip's live rows.
+    mesh: Any = None
+    ids: Optional[jax.Array] = None
+    shard_live: Tuple[int, ...] = ()
+
+    @property
+    def shards(self) -> int:
+        """Chips the rows are spread over (1 on one device)."""
+        return 1 if self.mesh is None else self.mesh.size
 
     @property
     def full(self) -> bool:
@@ -521,7 +536,8 @@ def pack_segments(
             )
         if inc is not None:
             return inc
-    with obs.span("packed.pack", rows=n_rows, segments=len(segments)):
+    with obs.span("packed.pack", rows=n_rows, segments=len(segments),
+                  shards=1, chip_rows=n_rows):
         view = _packed_view(config, views, bucket)
         return PackedSegments(
             view=view, bucket=bucket, n_rows=n_rows,
@@ -529,6 +545,69 @@ def pack_segments(
             live=_live_bitmap(segments, n_rows, bucket),
             any_deleted=any(s.del_count for s in segments),
             seg_names=names, seg_rows=rows,
+        )
+
+
+def _shard_ids(segments, shards: int, bucket: int) -> np.ndarray:
+    """(shards, bucket) global doc id of each chip's local row, -1 for pad
+    rows: each segment contributes its block of rows on every chip, in
+    order (``Segment.stored_rows`` says where each doc is stored)."""
+    ids = np.full((shards, bucket), -1, np.int32)
+    base = off = 0
+    for seg in segments:
+        block = seg.ann.num_docs // shards
+        stored = seg.stored_rows()
+        ids[stored // block, off + stored % block] = base + np.arange(seg.num_docs)
+        off += block
+        base += seg.num_docs
+    return ids
+
+
+def pack_segments_sharded(
+    config, views, segments, mesh, cache: Optional[ExecutableCache] = None
+) -> PackedSegments:
+    """Pack a doc-sharded snapshot per chip: each chip concatenates its own
+    rows of every segment and pads them to its own bucket, through the same
+    :func:`_packed_view` as one device, under ``shard_map``; the live bitmap
+    and the local-row -> global-id map are sharded with the rows.  No chip
+    sees another chip's rows.  The pack is compiled once per bucket and
+    segment layout (``EXEC_CACHE``), so an NRT refresh that keeps both
+    reuses it."""
+    cache = EXEC_CACHE if cache is None else cache
+    axes = tuple(mesh.axis_names)
+    shards = mesh.size
+    chip_rows = sum(s.ann.num_docs // shards for s in segments)
+    bucket = bucket_rows(chip_rows)
+    n_rows = sum(s.num_docs for s in segments)
+    with obs.span("packed.pack", rows=n_rows, segments=len(segments),
+                  shards=shards, chip_rows=chip_rows):
+        specs = [distributed.index_pspec(v, axes) for v in views]
+        args = (jax.device_put(list(views), [
+            jax.tree.map(lambda sp: NamedSharding(mesh, sp), spec) for spec in specs
+        ]),)
+
+        def build():
+            return jax.shard_map(
+                lambda vs: _packed_view(config, vs, bucket), mesh=mesh,
+                in_specs=(specs,), out_specs=distributed.index_pspec(views[0], axes),
+                check_vma=False,
+            )
+
+        view = cache.get(("sharded_pack", mesh, config, bucket), build, args)(*args)
+        ids = _shard_ids(segments, shards, bucket)
+        glive = np.concatenate([s.live for s in segments])
+        live = (ids >= 0) & glive[np.maximum(ids, 0)]
+        doc = NamedSharding(mesh, P(axes))
+        return PackedSegments(
+            view=view, bucket=bucket, n_rows=n_rows,
+            n_live=sum(s.num_live for s in segments),
+            live=jax.device_put(live.reshape(-1), doc),
+            any_deleted=any(s.del_count for s in segments),
+            seg_names=tuple(s.name for s in segments),
+            seg_rows=tuple(s.num_docs for s in segments),
+            mesh=mesh, ids=jax.device_put(ids.reshape(-1), doc),
+            # Host numpy: the bitmap is built here before its device_put.
+            shard_live=tuple(live.sum(axis=1).tolist()),  # reprolint: disable=hostsync
         )
 
 
@@ -585,7 +664,8 @@ def packed_search(
     bm=None,
     cache: Optional[ExecutableCache] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """ONE compiled launch for the whole segmented snapshot.
+    """ONE compiled launch for the whole segmented snapshot (across the
+    chips for a doc-sharded one, :func:`_sharded_search`).
 
     Mask selection (cheapest exact option first):
       * ``pk.full`` and no predicate — no mask at all: the exact unfiltered
@@ -607,6 +687,15 @@ def packed_search(
     k_out = min(k, d_eff)
     if k_out <= 0:
         raise ValueError("packed search over zero live docs")
+    if pk.mesh is not None:
+        if static_rows or n_keep is not None:
+            raise ValueError(
+                "a doc-sharded packed view takes no static_rows or blockmax"
+            )
+        return _sharded_search(
+            pk, pipeline, matcher, q_norm, k_out, d_eff, rerank, quantized,
+            use_kernel, fm, cache,
+        )
     q_rep = pipeline.encoder(pk.view, q_norm)
 
     use_filt = (fm is not None) or pk.any_deleted or (
@@ -655,3 +744,72 @@ def packed_search(
     exe = cache.get(key, build, args)
     pk.search_temp_bytes = cache.temp_bytes(exe)
     return exe(*args)
+
+
+def sharded_search_fn(
+    mesh, view_spec, matcher, depth: int, k: int, rerank: bool,
+    quantized: bool, use_kernel: bool, filtered: bool = False,
+):
+    """``fn(view, live, ids, fm, q_rep, q_norm) -> (scores, ids)`` over
+    per-chip packed views: each chip runs the match stage over its own rows
+    with its live bitmap (AND the predicate ``fm``, looked up through the
+    chip's global ids, when ``filtered``), maps its local rows to global
+    doc ids through ``ids``, and ``distributed.merge_global_depth`` merges.
+    Every per-doc operand is doc-sharded; queries and predicate are
+    replicated."""
+    axes = tuple(mesh.axis_names)
+
+    # Named for the trace: the executable's module is jit_sharded_packed_search.
+    def sharded_packed_search(view, live, ids, fm, q_rep, q_norm):
+        filt = live
+        if filtered:
+            # Pad rows (id -1) are dead in ``live`` already.
+            filt = live & jnp.take(fm, jnp.maximum(ids, 0), axis=-1)
+        loc_s, loc_i = matcher(view, q_rep, depth, use_kernel=use_kernel, filt=filt)
+        gid = jnp.where(loc_i >= 0, ids[jnp.maximum(loc_i, 0)], -1)
+        return distributed.merge_global_depth(
+            view, loc_s, loc_i, gid, q_norm, axes, k, depth, rerank, quantized
+        )
+
+    return jax.shard_map(
+        sharded_packed_search, mesh=mesh,
+        in_specs=(view_spec, P(axes), P(axes), P(), P(), P()),
+        out_specs=(P(), P()), check_vma=False,
+    )
+
+
+def _sharded_search(
+    pk: PackedSegments, pipeline, matcher, q_norm: jax.Array, k_out: int,
+    d_eff: int, rerank: bool, quantized: bool, use_kernel: Optional[bool],
+    fm: Optional[jax.Array], cache: ExecutableCache,
+) -> Tuple[jax.Array, jax.Array]:
+    """:func:`packed_search` over a doc-sharded packed view: one compiled
+    launch across the chips (:func:`sharded_search_fn`)."""
+    from repro.kernels.fused_topk import ops as fused
+
+    mesh = pk.mesh
+    uk = fused.resolve_use_kernel(use_kernel)
+    rep = NamedSharding(mesh, P())
+    q_rep = jax.device_put(pipeline.encoder(pk.view, q_norm), rep)
+    q_norm = jax.device_put(q_norm, rep)
+    fm_arg = None
+    if fm is not None:
+        # Global ids run below shards * bucket: one static width a bucket.
+        fm_arg = jax.device_put(
+            _pad_mask_cols(jnp.asarray(fm), pk.shards * pk.bucket), rep
+        )
+
+    def build():
+        return sharded_search_fn(
+            mesh, distributed.index_pspec(pk.view, mesh.axis_names), matcher,
+            d_eff, k_out, rerank, quantized, uk, filtered=fm is not None,
+        )
+
+    args = (pk.view, pk.live, pk.ids, fm_arg, q_rep, q_norm)
+    key = ("sharded_search", mesh, matcher, d_eff, k_out, rerank, quantized, uk)
+    exe = cache.get(key, build, args)
+    pk.search_temp_bytes = cache.temp_bytes(exe)
+    with obs.span("shard.merge", shards=pk.shards,
+                  bytes=distributed.merge_bytes(q_norm.shape[0], d_eff,
+                                                pk.shards, rerank)):
+        return exe(*args)

@@ -68,14 +68,16 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.core import bruteforce, builder, pca
+from repro.core import bruteforce, builder, distributed, pca
 from repro.core import index as index_mod
 from repro.core import packed as packed_mod
 from repro.core import pipeline as pl
 from repro.core.index import AnnIndex, AnyConfig
 from repro.core.types import (
+    BruteForceConfig,
     DocMetadata,
     FakeWordsConfig,
     KdTreeConfig,
@@ -177,17 +179,36 @@ class Segment:
     live: np.ndarray
     name: str
     source: Optional[np.ndarray] = None
+    # Doc-sharded segments (``IndexWriter(shards=N)``): the docs on each
+    # chip, in doc order.  Chip s stores its docs first in its block of
+    # ``ann.num_docs // N`` rows and zero pad rows after them.
+    shard_rows: Optional[Tuple[int, ...]] = None
+
+    def stored_rows(self) -> Optional[np.ndarray]:
+        """Stored row of each doc of a doc-sharded segment; None where the
+        segment stores doc j at row j."""
+        if self.shard_rows is None:
+            return None
+        block = self.ann.num_docs // len(self.shard_rows)
+        return np.concatenate(
+            [s * block + np.arange(n) for s, n in enumerate(self.shard_rows)]
+        )
 
     def source_rows(self) -> Optional[np.ndarray]:
         """Unit-normalized original rows (merge/refit operand), whichever
-        store carries them; None if the segment kept neither."""
+        store carries them, in doc order; None if the segment kept
+        neither."""
         if self.ann.index.vectors is not None:
-            return np.asarray(self.ann.index.vectors)
+            rows = np.asarray(self.ann.index.vectors)
+            stored = self.stored_rows()
+            return rows if stored is None else rows[stored]
         return self.source
 
     @property
     def num_docs(self) -> int:
-        """Total rows, deleted included (Lucene maxDoc)."""
+        """Total docs, deleted included (Lucene maxDoc)."""
+        if self.shard_rows is not None:
+            return sum(self.shard_rows)
         return self.ann.num_docs
 
     @property
@@ -204,7 +225,7 @@ class Segment:
         reader."""
         return Segment(
             ann=self.ann, live=self.live.copy(), name=self.name,
-            source=self.source,
+            source=self.source, shard_rows=self.shard_rows,
         )
 
 
@@ -363,6 +384,12 @@ class SegmentedAnnIndex:
     Doc ids are segment-stable: global id = segment base (sum of preceding
     segments' row counts, deleted included) + local row.  Ids survive
     deletes; merges compact and remap them (like Lucene).
+
+    With ``mesh`` (a doc-sharded writer's snapshot) every segment's rows
+    are spread over the mesh's chips, and search is one launch across them
+    over per-chip packed views with the global top-``depth`` merge
+    (``packed.packed_search`` on a doc-sharded view): the ids and answers
+    of one device over the same rows, filters included.
     """
 
     def __init__(
@@ -372,6 +399,7 @@ class SegmentedAnnIndex:
         use_kernel: Optional[bool] = None,
         global_stats: bool = True,
         epoch: Optional[int] = None,
+        mesh=None,
     ):
         if isinstance(config, KdTreeConfig) and config.backend == "tree":
             raise ValueError(
@@ -383,6 +411,7 @@ class SegmentedAnnIndex:
         self.segments = list(segments)
         self.use_kernel = use_kernel
         self.global_stats = global_stats
+        self.mesh = mesh
         self.epoch = next_epoch() if epoch is None else epoch
         self.pipeline = pl.build_pipeline(config)
         # Quantized rerank iff every segment carries ONLY the int8 store
@@ -423,6 +452,11 @@ class SegmentedAnnIndex:
         return len(self.segments)
 
     @property
+    def shards(self) -> int:
+        """Chips the snapshot's rows are spread over (1 on one device)."""
+        return 1 if self.mesh is None else self.mesh.size
+
+    @property
     def del_count(self) -> int:
         return self.max_doc - self._n_live
 
@@ -445,7 +479,7 @@ class SegmentedAnnIndex:
 
     def _ensure_views(self) -> Tuple[List[Any], List[pl.FilterMask]]:
         if self._views is None:
-            self._live_dev = [jnp.asarray(s.live) for s in self.segments]
+            self._live_dev = [self._live_operand(s) for s in self.segments]
             self._views = (
                 self._stat_views() if self.global_stats
                 else [s.ann.index for s in self.segments]
@@ -458,6 +492,18 @@ class SegmentedAnnIndex:
             for s in self.segments
         ]
         return self._views, matchers
+
+    def _live_operand(self, seg: Segment) -> jax.Array:
+        """A segment's live mask on the device, laid out like its rows: on
+        one device as it is, doc-sharded with pad rows dead on a mesh."""
+        stored = seg.stored_rows()
+        if stored is None:
+            return jnp.asarray(seg.live)
+        live = np.zeros(seg.ann.num_docs, bool)
+        live[stored] = seg.live
+        return jax.device_put(
+            live, NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
+        )
 
     # -- packed single-launch path (docs/DESIGN.md §14) ---------------------
 
@@ -472,6 +518,11 @@ class SegmentedAnnIndex:
         if self._packed_err is not None:
             return None
         views, _ = self._ensure_views()
+        if self.mesh is not None:
+            self._packed = packed_mod.pack_segments_sharded(
+                self.config, views, self.segments, self.mesh
+            )
+            return self._packed
         prior, self._packed_prior = self._packed_prior, None
         try:
             self._packed = packed_mod.pack_segments(
@@ -647,7 +698,14 @@ class SegmentedAnnIndex:
                     f"has max_doc={self.max_doc} (masks index GLOBAL ids, "
                     "deleted rows included)"
                 )
-        want_packed = _PACKED_DEFAULT if packed is None else bool(packed)
+        if self.mesh is not None and (blockmax_keep is not None or packed is False):
+            raise ValueError(
+                "a doc-sharded snapshot serves the packed single launch "
+                "only: no blockmax_keep or packed=False"
+            )
+        want_packed = self.mesh is not None or (
+            _PACKED_DEFAULT if packed is None else bool(packed)
+        )
         if blockmax_keep is not None and not want_packed:
             raise ValueError(
                 "blockmax_keep rides the packed single-launch path; "
@@ -829,6 +887,17 @@ class IndexWriter:
     host-side ``Segment.source`` sidecar (normalized once, persisted as
     ``source.npz``), so merges still rebuild live rows bit-for-bit and the
     kd-tree's global-stats refit still reads them.
+
+    ``shards=N`` spreads the collection over the first N local devices
+    (a 1-D mesh): each flush splits its rows into N contiguous parts that go
+    from the host straight to their chip and builds them there through the
+    sharded BuildPipeline stages, so no device holds a whole flush; global
+    df is summed over chips and segments as on one device.  Snapshots
+    serve through one launch across the chips (:class:`SegmentedAnnIndex`
+    with ``mesh``).  Fake words, lexical LSH and brute force, with every
+    rerank store and postings format; deletes, filters, refresh and merges
+    work as on one device, while metadata and commit points are one-device
+    only.
     """
 
     def __init__(
@@ -842,9 +911,20 @@ class IndexWriter:
         global_stats: bool = True,
         primary_postings: str = "fp32",
         postings_group: int = 32,
+        shards: int = 1,
     ):
         if rerank_store not in ("exact", "int8", "none"):
             raise ValueError(f"unknown rerank_store {rerank_store!r}")
+        if shards < 1:
+            raise ValueError(f"shards must be >= 1, got {shards}")
+        if shards > 1 and not (
+            isinstance(config, (FakeWordsConfig, LexicalLshConfig, BruteForceConfig))
+            and global_stats
+        ):
+            raise ValueError(
+                "shards > 1 serves fake words, lexical LSH or brute force "
+                "with global_stats=True"
+            )
         if isinstance(config, KdTreeConfig) and config.backend == "tree":
             raise ValueError(
                 "segmented kd-tree requires backend='scan' "
@@ -859,6 +939,8 @@ class IndexWriter:
         self.merge_policy = merge_policy
         self.max_buffered_docs = max_buffered_docs
         self.global_stats = global_stats
+        self.shards = shards
+        self.mesh = distributed.device_mesh(shards) if shards > 1 else None
         self._segments: List[Segment] = []
         self._buf: List[np.ndarray] = []
         self._buf_live: List[np.ndarray] = []
@@ -877,6 +959,8 @@ class IndexWriter:
         """Open the latest commit point under ``path`` for further writes
         (a plain v1 ``AnnIndex.save`` dir opens as one segment: the upgrade
         path from a frozen index to an online one)."""
+        if kwargs.get("shards", 1) > 1:
+            raise ValueError("commit points are one-device only (shards=1)")
         reader = SegmentedAnnIndex.load(path)
         kwargs.setdefault("use_kernel", reader.use_kernel)
         kwargs.setdefault("global_stats", reader.global_stats)
@@ -944,6 +1028,8 @@ class IndexWriter:
         if rows.ndim != 2 or rows.shape[0] == 0:
             raise ValueError(f"add expects (n, dim) rows, got {rows.shape}")
         md = builder.build_metadata(metadata, rows.shape[0])
+        if md is not None and self.mesh is not None:
+            raise ValueError("metadata is one-device only (shards=1)")
         start = self.total_docs
         self._buf.append(rows)
         self._buf_live.append(np.ones(rows.shape[0], bool))
@@ -993,20 +1079,25 @@ class IndexWriter:
         True when a segment was written."""
         if not self._buf:
             return False
-        with obs.span("writer.flush", rows=self.buffered_docs,
-                      segments=len(self._segments)):
+        n = self.buffered_docs
+        with obs.span("writer.flush", rows=n, segments=len(self._segments),
+                      shards=self.shards, chip_rows=-(-n // self.shards)):
             rows = np.concatenate(self._buf, axis=0)
             live = np.concatenate(self._buf_live, axis=0)
-            md = _concat_metadata(self._buf_md)
-            ann = self._build_segment(
-                jnp.asarray(rows), normalized=False, metadata=md
-            )
-            self._segments.append(
-                Segment(
-                    ann=ann, live=live, name=self._next_name(),
-                    source=self._source_sidecar(ann, rows, normalized=False),
+            if self.mesh is not None:
+                self._segments.append(self._build_sharded_segment(
+                    rows, live, normalized=False))
+            else:
+                md = _concat_metadata(self._buf_md)
+                ann = self._build_segment(
+                    jnp.asarray(rows), normalized=False, metadata=md
                 )
-            )
+                self._segments.append(
+                    Segment(
+                        ann=ann, live=live, name=self._next_name(),
+                        source=self._source_sidecar(ann, rows, normalized=False),
+                    )
+                )
             self._buf, self._buf_live, self._buf_md = [], [], []
             self._changed = True
             self.maybe_merge()
@@ -1023,6 +1114,42 @@ class IndexWriter:
             normalized=normalized,
             metadata=metadata,
         )
+
+    def _build_sharded_segment(
+        self, rows: np.ndarray, live: np.ndarray, normalized: bool
+    ) -> Segment:
+        """One segment spread over the mesh: contiguous parts of ``rows``
+        (sizes differ by at most one) padded with zero rows to one block per
+        chip, each part put from the host onto its own chip, then built
+        there by the sharded BuildPipeline stages."""
+        shards = self.mesh.size
+        parts = np.array_split(rows, shards)
+        block = max(1, len(parts[0]))
+        if len(rows) != shards * block:
+            padded = np.zeros((shards * block, rows.shape[1]), np.float32)
+            for s, part in enumerate(parts):
+                padded[s * block : s * block + len(part)] = part
+            rows = padded
+        axes = tuple(self.mesh.axis_names)
+        v = jax.device_put(rows, NamedSharding(self.mesh, P(axes, None)))
+        bp = builder.make_build_pipeline(
+            self.config, self.rerank_store, self.primary_postings,
+            self.postings_group,
+        )
+        idx = bp.sharded_build_fn(
+            self.mesh, axes, n_total=len(live), normalized=normalized
+        )(v)
+        ann = AnnIndex(config=self.config, index=idx, use_kernel=self.use_kernel)
+        seg = Segment(
+            ann=ann, live=live, name=self._next_name(),
+            shard_rows=tuple(len(p) for p in parts),
+        )
+        if idx.vectors is None:
+            # The merge operand when the store dropped the originals:
+            # normalized on the chips, as the build did, then fetched.
+            vn = v if normalized else bruteforce.l2_normalize(v)
+            seg.source = np.asarray(vn, np.float32)[seg.stored_rows()]
+        return seg
 
     @staticmethod
     def _source_sidecar(
@@ -1089,6 +1216,11 @@ class IndexWriter:
             del self._segments[start:end]
             self._changed = True
             return
+        if self.mesh is not None:
+            self._segments[start:end] = [self._build_sharded_segment(
+                rows, np.ones(rows.shape[0], bool), normalized=True)]
+            self._changed = True
+            return
         md = _concat_metadata(
             [s.ann.metadata for s in group], rows_kept=[s.live for s in group]
         )
@@ -1118,8 +1250,10 @@ class IndexWriter:
                     [s.snapshot() for s in self._segments],
                     use_kernel=self.use_kernel,
                     global_stats=self.global_stats,
+                    mesh=self.mesh,
                 )
-                if old is not None and packed_mod.stats_static(self.config):
+                if (old is not None and self.mesh is None
+                        and packed_mod.stats_static(self.config)):
                     # Hand the old snapshot's packed buffers to the new reader:
                     # an append-only refresh absorbs them via a donated
                     # incremental repack (core/packed.py).  The old reader
@@ -1144,6 +1278,8 @@ class IndexWriter:
         path = path if path is not None else self.path
         if path is None:
             raise ValueError("commit needs a path (or IndexWriter(path=...))")
+        if self.mesh is not None:
+            raise ValueError("commit points are one-device only (shards=1)")
         self.path = path
         self.flush()
         os.makedirs(path, exist_ok=True)

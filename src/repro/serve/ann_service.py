@@ -183,8 +183,8 @@ class AnnService:
         if self._segmented:
             if self.mesh is not None:
                 raise ValueError(
-                    "segmented serving is single-process; shard the corpus "
-                    "with mesh= over a monolithic index instead"
+                    "a segmented snapshot is spread over chips by its writer "
+                    "(IndexWriter(shards=N)); mesh= shards a monolithic index"
                 )
             if self._bm_keep is not None:
                 from repro.core.types import FakeWordsConfig, LexicalLshConfig
@@ -645,14 +645,21 @@ class AnnService:
             for k, v in packed_mod.EXEC_CACHE.stats().items()
         )
         pk = getattr(self.ann, "_packed", None)
+        shards = getattr(self.ann, "shards", 1)
+        out["shards"] = shards
+        out["merge_bytes_per_launch"] = distributed.merge_bytes(
+            self.scfg.max_batch, self.scfg.depth, shards, self.scfg.rerank)
+        out["shard_live_rows"] = None
         if pk is not None:
+            # Live rows each chip holds (one entry on one device).
+            out["shard_live_rows"] = list(pk.shard_live) or [pk.n_live]
             out["packed_bucket"] = pk.bucket
             # Compiled scratch of the search executable last run on this
             # snapshot: about 0 unless a call relays the corpus out.
             out["packed_search_temp_bytes"] = pk.search_temp_bytes
             out["packed_rows"] = pk.n_rows
             out["packed_live"] = pk.n_live
-            out["packed_occupancy"] = round(pk.n_rows / pk.bucket, 4)
+            out["packed_occupancy"] = round(pk.n_rows / (pk.bucket * pk.shards), 4)
             out["packed_appends"] = pk.appends
         else:
             out["packed_bucket"] = None

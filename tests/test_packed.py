@@ -405,50 +405,58 @@ def test_packed_unsupported_falls_back_and_true_raises(rng):
         reader.search(queries, k=5, depth=20, packed=True)
 
 
-def test_packed_sharded_composition(rng):
-    """make_packed_segmented_search: pack -> doc-shard -> pod fan-out with
-    the live∧predicate bitmap sharded with the rows (subprocess with 8
-    fake host devices, like tests/test_distributed.py)."""
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
+def test_packed_sharded_composition():
+    """The packed single launch over a doc-sharded writer's snapshot (four
+    host devices, in a subprocess, like tests/test_distributed.py) takes
+    what the one-device packed path takes: two flushes, deletes, a
+    (max_doc,) and a (B, max_doc) predicate composed with the live bitmap,
+    the rerank, the int8 rerank store and int8 postings.  Ids are the
+    one-device writer's; scores agree within 2**-22 (the bound of
+    tests/test_sharded_writer.py, which states its reason)."""
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
     code = textwrap.dedent("""
         import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.core import bruteforce, distributed
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax.numpy as jnp, numpy as np
         from repro.core.segments import IndexWriter
         from repro.core.types import FakeWordsConfig
 
         rng = np.random.default_rng(0)
-        w = IndexWriter(FakeWordsConfig(quantization=50), merge_policy=None,
-                        use_kernel=False)
-        w.add(rng.normal(size=(300, 32)).astype(np.float32)); w.flush()
-        w.add(rng.normal(size=(212, 32)).astype(np.float32)); w.flush()
-        w.delete(rng.choice(512, size=40, replace=False))
-        reader = w.refresh()  # 512 rows -> bucket 512: divisible by 4
-        queries = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
-        mesh = jax.make_mesh((4,), ("data",))
-        fn, idx_sh, filt_sh = distributed.make_packed_segmented_search(
-            mesh, reader, ("data",), k=10, depth=50, rerank=True,
-            use_kernel=False)
-        q_rep = reader.encode_queries(queries)
-        s_sh, i_sh = fn(idx_sh, q_rep, bruteforce.l2_normalize(queries),
-                        filt_sh)
-        s_1, i_1 = reader.search(queries, k=10, depth=50, rerank=True,
-                                 packed=False)
-        # Rerank fp rounding differs per shard partition; like the other
-        # sharded suites, assert set overlap + score closeness, not
-        # bitwise id order.
-        from repro.core import eval as ev
-        ov = float(ev.overlap(i_1, i_sh))
-        assert ov >= 0.95, ov
-        np.testing.assert_allclose(np.asarray(s_1)[:, :8],
-                                   np.asarray(s_sh)[:, :8],
-                                   rtol=1e-4, atol=1e-5)
-        print("packed sharded ok", ov)
+        rows = rng.normal(size=(512, 32)).astype(np.float32)
+        dels = rng.choice(512, size=40, replace=False)
+        q = jnp.asarray(rng.normal(size=(4, 32)).astype(np.float32))
+        pred = rng.random(512) < 0.5
+        pred2 = rng.random((4, 512)) < 0.3
+        for kw in ({}, {"rerank_store": "int8"}, {"primary_postings": "int8"}):
+            readers = []
+            for shards in (4, 1):
+                w = IndexWriter(FakeWordsConfig(quantization=50), merge_policy=None,
+                                shards=shards, **kw)
+                w.add(rows[:300]); w.flush()
+                w.add(rows[300:]); w.flush()
+                w.delete(dels)
+                readers.append(w.refresh())
+            assert readers[0].shards == 4 and readers[0].packed_segments().ids is not None
+            for fm in (None, pred, pred2):
+                for rerank in (False, True):
+                    (s4, i4), (s1, i1) = [
+                        r.search(q, k=10, depth=50, rerank=rerank, filter_mask=fm)
+                        for r in readers
+                    ]
+                    i4, s4, s1 = np.asarray(i4), np.asarray(s4), np.asarray(s1)
+                    np.testing.assert_array_equal(i4, np.asarray(i1))
+                    np.testing.assert_array_equal(np.isfinite(s4), np.isfinite(s1))
+                    fin = np.isfinite(s1)
+                    assert np.max(np.abs(s4[fin] - s1[fin])) <= 2.0 ** -22, (kw, rerank)
+                    assert not set(i4[i4 >= 0].tolist()) & set(dels.tolist())
+                    if fm is not None:
+                        keep = np.broadcast_to(fm, (4, 512))
+                        assert all(keep[b, i] for b in range(4) for i in i4[b] if i >= 0)
+        print("packed sharded ok")
     """)
     r = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=900, env=dict(os.environ, PYTHONPATH=src),
+        timeout=900, env=dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu"),
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
 

@@ -533,7 +533,7 @@ def test_writer_guard_rails(rng):
         w.commit()
     w.add(a[:64])
     reader = w.refresh()
-    with pytest.raises(ValueError, match="single-process"):
+    with pytest.raises(ValueError, match=r"IndexWriter\(shards=N\)"):
         AnnService(reader, mesh=object())  # type: ignore[arg-type]
 
 

@@ -333,3 +333,54 @@ def test_ann_service_stats_mutations_hold_lock(small_corpus):
     assert svc.stats()["rejected"] == rejected
     assert isinstance(svc._req_lat, GuardedHistogram) and svc._req_lat.n == 0
     assert violations == []
+
+
+SHARDED_SPANS = r"""
+import glob, json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.core.segments import IndexWriter
+from repro.core.types import FakeWordsConfig
+from repro.serve.ann_service import AnnService, AnnServiceConfig
+rng = np.random.default_rng(3)
+w = IndexWriter(FakeWordsConfig(), shards=4, merge_policy=None)
+jax.profiler.start_trace(sys.argv[1])
+w.add(rng.normal(size=(1001, 32)).astype(np.float32))
+svc = AnnService(writer=w, service=AnnServiceConfig(k=10, depth=50, max_batch=16))
+svc.search_batch(rng.normal(size=(16, 32)).astype(np.float32))
+jax.profiler.stop_trace()
+(path,) = glob.glob(os.path.join(sys.argv[1], "**", "*.xplane.pb"), recursive=True)
+spans = {ev.name: dict(ev.stats) for plane in jax.profiler.ProfileData.from_file(path).planes
+         for line in plane.lines for ev in line.events
+         if ev.name in ("writer.flush", "packed.pack", "shard.merge")}
+stats = svc.stats()
+print(json.dumps({"spans": spans, "stats": {k: stats[k] for k in
+      ("shards", "shard_live_rows", "merge_bytes_per_launch", "packed_bucket")}}))
+"""
+
+
+def test_sharded_service_stats_and_spans(tmp_path):
+    """A doc-sharded writer's service (four host devices, in a subprocess):
+    ``stats()`` gives the chips, each chip's live rows and the merge's bytes
+    per launch, and the writer's flush, the per-chip pack and the sharded
+    dispatch carry ``shards`` and the rows a chip holds."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
+    r = subprocess.run(
+        [sys.executable, "-c", SHARDED_SPANS, str(tmp_path)], capture_output=True,
+        text=True, timeout=600, env=dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu"),
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    spans, stats = out["spans"], out["stats"]
+    # 1,001 rows over four chips: 251 + 250 + 250 + 250, in blocks of 251.
+    assert spans["writer.flush"] == {"rows": 1001, "segments": 0, "shards": 4, "chip_rows": 251}
+    assert spans["packed.pack"] == {"rows": 1001, "segments": 1, "shards": 4, "chip_rows": 251}
+    # 16 queries x depth 50 x (4 chips' (score, id) pairs + one rerank score).
+    assert spans["shard.merge"] == {"shards": 4, "bytes": 16 * 50 * (8 * 4 + 4)}
+    assert stats == {"shards": 4, "shard_live_rows": [251, 250, 250, 250],
+                     "merge_bytes_per_launch": 16 * 50 * 36, "packed_bucket": 256}
